@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.index import AnchorIndex
+from repro.launch.mesh import make_mesh
 
 from .common import emit
 
@@ -126,7 +127,7 @@ def run(
              f"add_s={add_s:.4f};remove_s={remove_s:.4f}")
 
         # -- sharded-search parity over all local devices --------------------
-        mesh = jax.make_mesh((jax.device_count(),), ("data",))
+        mesh = make_mesh((jax.device_count(),), ("data",))
         sharded = index.shard(mesh)
         t = _timer()
         vs, is_ = jax.block_until_ready(sharded.topk(e_q, 100))

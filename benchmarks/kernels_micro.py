@@ -41,9 +41,8 @@ def run(quiet: bool = False):
     bq, kq, n, kk = 8, 500, 100_000, 64
     e_q = jax.random.normal(ks[0], (bq, kq))
     r = jax.random.normal(ks[1], (kq, n))
-    anchors = jnp.full((bq, 8), -1, jnp.int32)
-    _, us_ref = timed(lambda: approx_topk_reference(e_q, r, anchors, kk), warmup=1)
-    _, us_pal = timed(lambda: approx_topk_op(e_q, r, anchors, kk, tile=4096, interpret=True), warmup=1)
+    _, us_ref = timed(lambda: approx_topk_reference(e_q, r, kk), warmup=1)
+    _, us_pal = timed(lambda: approx_topk_op(e_q, r, kk, tile=4096, interpret=True), warmup=1)
     scores_bytes = 2 * bq * n * 4                      # write + read back
     out_bytes = bq * (n // 4096) * kk * 8
     emit("kernels/approx_topk_N100k", us_pal,
@@ -58,16 +57,16 @@ def run(quiet: bool = False):
         noise = jax.random.gumbel(ks[2], (bq, n))
 
         def staged():
-            s = approx_topk_op(e_q, payload, anchors, kk, tile=4096,
-                               interpret=True, noise=noise)
-            p = approx_topk_op(e_q, payload, None, kk, tile=4096,
-                               interpret=True, mask=mask)
+            s = approx_topk_op(e_q, payload, kk, tile=4096, interpret=True,
+                               noise=noise, mask=mask)
+            p = approx_topk_op(e_q, payload, kk, tile=4096, interpret=True,
+                               mask=mask)
             return s, p
 
         _, us_staged = timed(staged, warmup=1)
         _, us_per = timed(
             lambda: persistent_round_op(
-                e_q, payload, k_sample=kk, k_prov=kk, anchors=anchors,
+                e_q, payload, k_sample=kk, k_prov=kk, mask=mask,
                 noise=noise, prov_mask=mask, tile=4096, interpret=True,
             ),
             warmup=1,

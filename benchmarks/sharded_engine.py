@@ -50,10 +50,11 @@ def _worker(args) -> None:
     from repro.core.engine import engine_slab_bytes, make_engine, make_sharded_engine
     from repro.core.index import AnchorIndex
     from repro.data.synthetic import make_synthetic_ce
+    from repro.launch.mesh import make_serving_mesh
 
     n_dev = args.worker
     assert len(jax.devices()) == n_dev, (len(jax.devices()), n_dev)
-    mesh = jax.make_mesh((1, n_dev), ("data", "items"))
+    mesh = make_serving_mesh(1, n_dev)
 
     def bench_one(n_items: int) -> dict:
         ce = make_synthetic_ce(jax.random.PRNGKey(0), n_queries=80,

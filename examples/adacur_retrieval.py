@@ -9,9 +9,11 @@ import jax
 
 from benchmarks import recall_budget
 from benchmarks.common import make_domain
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     dom = make_domain()
     print("domain: 10,000 items, 500 anchor queries, 200 test queries")
     print("name,us_per_call,derived")

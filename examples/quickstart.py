@@ -25,9 +25,11 @@ from repro.core.engine import AdaCURRetriever, ANNCURRetriever
 from repro.core.index import AnchorIndex
 from repro.core.scorer import SyntheticScorer
 from repro.data.synthetic import make_synthetic_ce
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--payload-dtype",
                     choices=("float32", "bfloat16", "int8", "int4", "fp8"),

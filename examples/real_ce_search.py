@@ -27,10 +27,12 @@ from repro.core import engine
 from repro.core.index import AnchorIndex
 from repro.core.scorer import CachingScorer, CrossEncoderScorer
 from repro.data.synthetic import make_zeshel_like
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import cross_encoder
 
 
 def main():
+    enable_compile_cache()
     n_items, n_anchor_q, n_test_q = 300, 60, 16
     print(f"corpus: {n_items} entity descriptions, {n_anchor_q} anchor queries")
     ds = make_zeshel_like(0, n_items=n_items, n_queries=n_anchor_q + n_test_q,

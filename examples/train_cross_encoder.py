@@ -30,6 +30,7 @@ from repro.core.engine import AdaCURRetriever, ANNCURRetriever
 from repro.core.index import AnchorIndex
 from repro.data.synthetic import make_zeshel_like
 from repro.distributed.fault_tolerance import StragglerWatchdog
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import cross_encoder
 from repro.training import optimizer
 
@@ -57,6 +58,7 @@ def tfidf_retriever(ds):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--n-items", type=int, default=800)

@@ -242,7 +242,8 @@ class AdaCURConfig:
     # approximate score matrix is never materialized in HBM.
     use_fused_topk: bool = False
     fused_tile: int = 6144           # item-axis tile of the fused kernel
-    fused_interpret: bool = True     # interpret-mode Pallas (CPU); False on TPU
+                                     # (the platform picks compiled Pallas on
+                                     # TPU, the scan emulation on CPU)
     # Anytime ADACUR (fori mode only): stop early once the round-over-round
     # provisional top-k_retrieve candidate set overlap reaches 1 - tol.
     # 0.0 always runs the full round budget.

@@ -101,7 +101,6 @@ class DualEncoderCandidates:
     i_emb: jax.Array                    # (N, d) corpus item embeddings
     n_valid: Optional[int] = None       # static valid-prefix bound
     tile: int = 1024
-    interpret: bool = True
     stats: GeneratorStats = field(default_factory=GeneratorStats)
 
     def __post_init__(self):
@@ -117,8 +116,7 @@ class DualEncoderCandidates:
         self.stats.candidates += int(qids.shape[0]) * k
         e = jnp.take(self._q_emb, qids, axis=0)
         _, idx = approx_topk_op(
-            e, self._i_emb_t, None, k, tile=self.tile,
-            interpret=self.interpret, n_valid=self.n_valid,
+            e, self._i_emb_t, k, tile=self.tile, n_valid=self.n_valid,
         )
         return idx
 

@@ -24,6 +24,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# The CUR products and solves are fp32 and small; on a TPU the default
+# matmul precision would round their operands to bf16 (one MXU pass) and
+# drift the anchor selection.  Each call site asks for full fp32 instead —
+# never a process-wide flag, which would also slow the bf16 CE forward.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def pinv(a: jax.Array, rcond: float = 1e-6) -> jax.Array:
     """Moore-Penrose pseudo-inverse (SVD-based, batched over leading dims)."""
@@ -65,7 +71,7 @@ def query_embedding(
     (B,k_q)x(k_q,N) GEMM.
     """
     u = pinv(r_anc_cols, rcond)  # (B, k, k_q)
-    return jnp.einsum("bk,bkq->bq", c_test, u)
+    return jnp.einsum("bk,bkq->bq", c_test, u, precision=HIGHEST)
 
 
 def approx_scores(
@@ -102,16 +108,19 @@ def _bordered_blocks(
     D = P @ B, C = B - A @ D, and K is pinv(C) blended with the Greville
     fallback ``K = (I + DᵀD)⁻¹ Dᵀ P`` per-column when the residual C is
     (numerically) rank-deficient — new columns inside span(A)."""
-    d = p @ b                                      # (n, s)
-    c = b - a @ d                                  # (m, s)
+    d = jnp.matmul(p, b, precision=HIGHEST)        # (n, s)
+    c = b - jnp.matmul(a, d, precision=HIGHEST)    # (m, s)
     # full-column-rank branch: K1 = (CᵀC + ridge I)⁻¹ Cᵀ
-    gram = c.T @ c
+    gram = jnp.matmul(c.T, c, precision=HIGHEST)
     s = gram.shape[-1]
     eye = jnp.eye(s, dtype=gram.dtype)
     scale = jnp.trace(gram) / s + 1.0
     k1 = jnp.linalg.solve(gram + ridge * scale * eye, c.T)
     # rank-deficient branch: K2 = (I + DᵀD)⁻¹ Dᵀ P
-    k2 = jnp.linalg.solve(eye + d.T @ d, d.T @ p)
+    k2 = jnp.linalg.solve(
+        eye + jnp.matmul(d.T, d, precision=HIGHEST),
+        jnp.matmul(d.T, p, precision=HIGHEST),
+    )
     # DUPLICATE new columns (coarse payload grids — int4 especially — make
     # exact column collisions likely) leave gram singular with a large
     # trace, and the ridge underflows against the fp32 rounding of the
@@ -155,7 +164,7 @@ def block_pinv_extend(
     (s,s) solves well-posed.
     """
     d, k = _bordered_blocks(a, p, b, ridge)
-    top = p - d @ k
+    top = p - jnp.matmul(d, k, precision=HIGHEST)
     return jnp.concatenate([top, k], axis=0)
 
 
@@ -177,7 +186,8 @@ def block_pinv_extend_static(
     loop body is trace-invariant and ``start`` may be a traced index.
     """
     d, k = _bordered_blocks(a_full, p_full, b, ridge)
-    top = p_full - d @ k        # rows >= start stay exactly zero (p, d zero)
+    # rows >= start stay exactly zero (p, d zero)
+    top = p_full - jnp.matmul(d, k, precision=HIGHEST)
     return jax.lax.dynamic_update_slice(top, k, (start, 0))
 
 

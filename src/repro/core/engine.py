@@ -55,12 +55,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
 from ..configs.base import AdaCURConfig, replace
 from ..kernels.approx_topk import quant
 from ..kernels.approx_topk.ops import approx_topk_op
 from ..kernels.approx_topk.persistent import persistent_round_op
 from ..kernels.approx_topk.quant import QuantizedRanc
+from ..kernels.backend import on_tpu
 from . import cur, sampling
 from .adacur import AdaCURResult, ScoreFn
 
@@ -403,17 +403,17 @@ def _global_frac(ctx: ShardCtx, hit: jax.Array) -> jax.Array:
 def _effective_tile(cfg: AdaCURConfig, r_anc) -> int:
     """Item-tile width of the fused kernel for this payload.
 
-    On the CPU scan backend (``fused_interpret``) the binding constraint is
+    The compiled TPU kernel keeps the configured column count: its VMEM
+    budget is dominated by the (B, T) fp32 score (and noise/mask) blocks,
+    which do NOT shrink with the payload dtype — widening T there would
+    blow VMEM; the int8 win on TPU is the 4x smaller HBM stream per
+    (unchanged) tile.  On the CPU scan backend the binding constraint is
     the payload tile's L2 residency, so ``cfg.fused_tile`` acts as a
     per-tile *byte* budget expressed in fp32 columns: a quantized payload
     streams proportionally more columns in the same footprint (x4 int8,
     x2 bf16) — which is where the ~4x fewer bytes per round turn into
-    wall-clock on CPU.  The compiled TPU kernel keeps the configured column
-    count: its VMEM budget is dominated by the (B, T) fp32 score (and
-    noise/mask) blocks, which do NOT shrink with the payload dtype —
-    widening T 4x there would blow VMEM; the int8 win on TPU is the 4x
-    smaller HBM stream per (unchanged) tile."""
-    if not cfg.fused_interpret:
+    wall-clock on CPU."""
+    if on_tpu():
         return cfg.fused_tile
     dtype = quant.payload_dtype_of(r_anc)
     if dtype == "int4":
@@ -423,23 +423,6 @@ def _effective_tile(cfg: AdaCURConfig, r_anc) -> int:
     if dtype == "bfloat16":
         return cfg.fused_tile * 2
     return cfg.fused_tile
-
-
-def _fused_suppress(
-    cfg: AdaCURConfig, state: EngineState, force_mask: bool = False
-) -> dict:
-    """How the fused op suppresses already-selected items, per backend.
-
-    On TPU (compiled kernel) the (B, k_i) anchor-id list stays resident in
-    VMEM and is compared per tile — no (B, N) traffic.  On the CPU scan
-    backend the engine's existing (B, N) bool ``selected`` mask is streamed
-    tile-by-tile instead: O(B·T) per tile beats the O(B·T·A) id compare.
-    ``force_mask`` routes through the mask even on TPU — required when the
-    valid-item bound is a *runtime* value (dynamic corpora), because the
-    anchor-id compare cannot see the invalid padded tail."""
-    if cfg.fused_interpret or force_mask:
-        return dict(anchors=None, mask=state.selected)
-    return dict(anchors=state.anchor_idx, mask=None)
 
 
 def _bcast_mask(invalid, b: int, n: int):
@@ -458,7 +441,6 @@ def _sample_round(
     k_eff: int,
     n_valid: Optional[int],
     ctx: ShardCtx,
-    force_mask: bool = False,
     monitor: Optional[tuple] = None,
 ):
     """One adaptive round's anchor pick (Alg. 3) — dense or fused, over this
@@ -505,14 +487,12 @@ def _sample_round(
         if cfg.strategy == "softmax":
             logits = logits + _noise(ctx, key, b)
         return with_monitor(_local_topk_merge(ctx, logits, k_eff))
-    suppress = _fused_suppress(cfg, state, force_mask or sharded)
+    # already-selected items are suppressed through the engine's (B, N)
+    # ``selected`` mask, streamed tile by tile next to the payload
     tile = _effective_tile(cfg, r_anc)
     nv = None if sharded else n_valid
     if persistent:
-        kw = dict(
-            k_sample=k_eff, tile=tile, interpret=cfg.fused_interpret,
-            n_valid=nv, **suppress,
-        )
+        kw = dict(k_sample=k_eff, tile=tile, n_valid=nv, mask=state.selected)
         e_q = state.e_q
         if cfg.strategy == "softmax":
             # temp folds into e_q (scores/temp == (e_q/temp) @ R_anc), as on
@@ -549,14 +529,14 @@ def _sample_round(
         g = _noise(ctx, key, b)
         e_q = state.e_q / jnp.asarray(cfg.softmax_temp, state.e_q.dtype)
         v, idx = approx_topk_op(
-            e_q, r_anc, k=k_eff, tile=tile,
-            interpret=cfg.fused_interpret, noise=g, n_valid=nv, **suppress,
+            e_q, r_anc, k=k_eff, tile=tile, noise=g, n_valid=nv,
+            mask=state.selected,
         )
     else:
         # topk: temp > 0 is order-preserving, no noise needed
         v, idx = approx_topk_op(
-            state.e_q, r_anc, k=k_eff, tile=tile,
-            interpret=cfg.fused_interpret, n_valid=nv, **suppress,
+            state.e_q, r_anc, k=k_eff, tile=tile, n_valid=nv,
+            mask=state.selected,
         )
     if not sharded:
         return with_monitor(idx)
@@ -573,7 +553,6 @@ def _make_round_steps(
     k_s: int,
     n_valid: Optional[int],
     ctx: ShardCtx,
-    force_mask: bool = False,
 ):
     """The shape-invariant adaptive round, split into its two stages.
 
@@ -596,7 +575,7 @@ def _make_round_steps(
     def sample(r, state: EngineState, monitor=None):
         return _sample_round(
             cfg, keys[r], state, r_anc, k_s - n_rand, n_valid, ctx,
-            force_mask, monitor=monitor,
+            monitor=monitor,
         )
 
     def apply(r, state: EngineState, idx_new) -> EngineState:
@@ -634,7 +613,7 @@ def _make_round_steps(
                 state.a_buf, cols_new, (0, 0, start)
             )
             p = cur.pinv(a_buf, cfg.pinv_rcond)     # zero cols -> zero rows
-        e_q = jnp.einsum("bk,bkq->bq", c_test, p)
+        e_q = jnp.einsum("bk,bkq->bq", c_test, p, precision=cur.HIGHEST)
         return EngineState(anchor_idx, c_test, a_buf, p, e_q, selected)
 
     def body(r, state: EngineState) -> EngineState:
@@ -652,11 +631,10 @@ def _make_round_body(
     k_s: int,
     n_valid: Optional[int],
     ctx: ShardCtx,
-    force_mask: bool = False,
 ) -> Callable[[jax.Array, EngineState], EngineState]:
     """The staged round body — ``apply ∘ sample`` (see _make_round_steps)."""
     return _make_round_steps(
-        scored, r_anc, query, cfg, keys, k_s, n_valid, ctx, force_mask
+        scored, r_anc, query, cfg, keys, k_s, n_valid, ctx
     )[2]
 
 
@@ -680,8 +658,7 @@ def _provisional_topk(
             else jnp.broadcast_to(invalid, (e_q.shape[0], r_anc.shape[1]))
         )
         v, idx = approx_topk_op(
-            e_q, r_anc, None, m, tile=_effective_tile(cfg, r_anc),
-            interpret=cfg.fused_interpret,
+            e_q, r_anc, m, tile=_effective_tile(cfg, r_anc),
             n_valid=None if sharded else n_valid, mask=mask,
         )
         if not sharded:
@@ -849,7 +826,6 @@ def engine_search(
         mon_invalid = (
             ~eligible if invalid is None else (~eligible | invalid[None, :])
         )
-    dyn_valid = invalid is not None or eligible is not None
     if cfg.loop_mode == "unrolled" and n_rounds is not None:
         raise ValueError("runtime n_rounds override requires loop_mode='fori'")
     if deadline is not None:
@@ -951,14 +927,14 @@ def engine_search(
             else cur.pinv(cols0, cfg.pinv_rcond)
         )
         p = p.at[:, :k_s, :].set(p0)
-        e_q = jnp.einsum("bk,bkq->bq", c_test, p)
+        e_q = jnp.einsum("bk,bkq->bq", c_test, p, precision=cur.HIGHEST)
     else:
         p = jnp.zeros((b, k_i, k_q), dtype)
         e_q = jnp.zeros((b, k_q), dtype)
     state = EngineState(anchor_idx, c_test, a_buf, p, e_q, selected)
 
     sample_step, apply_step, body = _make_round_steps(
-        scored, r_anc, query, cfg, keys, k_s, n_valid, ctx, force_mask=dyn_valid
+        scored, r_anc, query, cfg, keys, k_s, n_valid, ctx
     )
 
     # --- rounds 1..n_rounds-1 ----------------------------------------------
@@ -1068,9 +1044,7 @@ def engine_search(
     if cfg.use_fused_topk:
         v_r, rerank_idx = approx_topk_op(
             state.e_q, r_anc, k=k_r, tile=_effective_tile(cfg, r_anc),
-            interpret=cfg.fused_interpret,
-            n_valid=None if sharded else n_valid,
-            **_fused_suppress(cfg, state, dyn_valid or sharded),
+            n_valid=None if sharded else n_valid, mask=state.selected,
         )
         if sharded:
             _, rerank_idx = _merge_topk(
@@ -1145,9 +1119,9 @@ def make_engine(
     if jit_compile:
         _run = partial(jax.jit, static_argnames=("batch",))(_run)
 
-    def run(r_anc, query, key, first_anchors=None, batch=None, n_rounds=None,
-            n_valid=None, item_ids=None, eligible=None, pos_map=None,
-            item_tokens=None):
+    def operands(r_anc, query, key, first_anchors=None, batch=None,
+                 n_rounds=None, n_valid=None, item_ids=None, eligible=None,
+                 pos_map=None, item_tokens=None):
         if cfg.loop_mode == "fori":
             n_rounds = jnp.asarray(
                 cfg.n_rounds if n_rounds is None else n_rounds, jnp.int32
@@ -1156,9 +1130,15 @@ def make_engine(
             raise ValueError("runtime n_rounds override requires loop_mode='fori'")
         if n_valid is not None:
             n_valid = jnp.asarray(n_valid, jnp.int32)
-        return _run(r_anc, query, key, n_rounds, first_anchors, batch,
-                    n_valid, item_ids, eligible, pos_map, item_tokens)
+        return (r_anc, query, key, n_rounds, first_anchors, batch,
+                n_valid, item_ids, eligible, pos_map, item_tokens)
 
+    def run(*args, **kw):
+        return _run(*operands(*args, **kw))
+
+    if jit_compile:
+        # the compiled program behind ``run`` for these operands
+        run.lower = lambda *args, **kw: _run.lower(*operands(*args, **kw))
     run.deadline = deadline
     return run
 
@@ -1416,7 +1396,7 @@ def make_sharded_engine(
                     )
                     return core(*full)
 
-                return shard_map(
+                return jax.shard_map(
                     body, mesh=mesh, in_specs=live_specs,
                     out_specs=out_specs, check_vma=False,
                 )(*live)
@@ -1457,10 +1437,9 @@ class _IndexBacked:
     changes values only — shapes are capacity-constant and nothing retraces.
 
     The runtime ``n_valid`` bound is only passed when the index is (or was
-    constructed) padded: an unpadded index keeps the engine's static path,
-    whose fused TPU sampling suppresses via the compact anchor-id list
-    instead of a (B, N) mask.  Removing items from an unpadded index flips
-    it to the dynamic path (one retrace, then stable).
+    constructed) padded: an unpadded index keeps the engine's static
+    ``n_valid`` bound.  Removing items from an unpadded index flips it to
+    the dynamic path (one retrace, then stable).
 
     ``cfg.payload_dtype`` is applied to the index ONCE at construction
     (:meth:`_apply_payload_policy`): the engine then receives an already
@@ -1579,6 +1558,16 @@ class AdaCURRetriever(_IndexBacked):
         through ``index.item_ids``), padded capacity is masked through the
         runtime ``n_valid`` bound, and index mutation never retraces."""
         return cls(score_fn, None, cfg, index=index, jit=jit, anytime=anytime)
+
+    def lower(self, query, key=None):
+        """The engine program :meth:`search` runs for this query batch, as a
+        ``jax.stages.Lowered`` — ``.compile().as_text()`` shows which
+        kernels it contains.  Single-device compiled engines only."""
+        if self._sharded or not self.jit:
+            raise ValueError("lower() covers the single-device compiled engine")
+        key = jax.random.PRNGKey(0) if key is None else key
+        r_anc, kw = self._search_operands()
+        return self._run.lower(r_anc, self._prep_query(query), key, **kw)
 
     def search(self, query, key=None, first_anchors=None, batch=None,
                n_rounds=None, deadline_t=None, **_ignored):

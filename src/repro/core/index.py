@@ -44,7 +44,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..checkpoint.checkpointer import Checkpointer
-from ..compat import shard_map
 from ..distributed import sharding
 from ..kernels.approx_topk import quant
 from ..kernels.approx_topk.ops import approx_topk_op
@@ -769,7 +768,6 @@ class AnchorIndex:
         mesh: Optional[Mesh] = None,
         item_axes: Optional[Tuple[str, ...]] = None,
         tile: int = 512,
-        interpret: bool = True,
     ) -> Tuple[jax.Array, jax.Array]:
         """Top-k of ``e_q @ R_anc`` over *valid* items -> (vals, positions).
 
@@ -788,7 +786,7 @@ class AnchorIndex:
         if mesh is None:
             mask = jnp.broadcast_to(invalid[None, :], (b, self.capacity))
             return approx_topk_op(
-                e_q, self.r_anc, None, k, tile=tile, interpret=interpret, mask=mask
+                e_q, self.r_anc, k, tile=tile, mask=mask
             )
         axes = item_axes
         if axes is None:
@@ -815,8 +813,7 @@ class AnchorIndex:
                 r_local = QuantizedRanc(r_local, scales_local, tile_q, cdt)
             mask = jnp.broadcast_to(inv_local[None, :], (eq.shape[0], n_local))
             v, i = approx_topk_op(
-                eq, r_local, None, k, tile=min(tile, n_local),
-                interpret=interpret, mask=mask,
+                eq, r_local, k, tile=min(tile, n_local), mask=mask,
             )
             gi = i + shard_id * n_local
             vg = jax.lax.all_gather(v, axes, axis=1, tiled=True)   # (B, S*k)
@@ -828,7 +825,7 @@ class AnchorIndex:
             payload_args = (self.r_anc.codes, self.r_anc.scales)
         else:
             payload_args = (self.r_anc, jnp.zeros((n_shards,), jnp.float32))
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(None, axes), P(axes), P(axes)),
             out_specs=(P(), P()),

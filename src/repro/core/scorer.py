@@ -57,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import LMConfig
+from ..kernels.backend import resolve_attn_impl
 
 
 @dataclass
@@ -216,8 +217,9 @@ class CrossEncoderScorer(_HostScorer):
     Pairs are flattened, padded to the smallest length bucket, and scored
     in fixed ``micro_batch``-row chunks, so the jitted compute sees only
     ``len(len_buckets)`` static shapes — ``n_traces`` proves repeated calls
-    never retrace.  Attention runs through the Pallas flash kernel with
-    per-example SMEM valid lengths (``attn_impl='flash'``).
+    never retrace.  On a TPU attention runs through the Pallas flash kernel
+    with per-example SMEM valid lengths (the platform picks ``attn_impl``;
+    see ``kernels/backend.py``).
 
     Pair lengths are validated *eagerly*: at construction the ``pair_fn``
     is probed with a one-pair dummy call, so a pair that overflows the
@@ -241,9 +243,8 @@ class CrossEncoderScorer(_HostScorer):
         pad_id: int = 0,
         micro_batch: int = 64,
         len_buckets: Tuple[int, ...] = (32, 64, 128, 256, 512),
-        attn_impl: str = "flash",
+        attn_impl: Optional[str] = None,
         flash_block: Tuple[int, int] = (128, 128),
-        flash_interpret: bool = True,
         record_pairs: bool = False,
         probe_pair_len: bool = True,
     ):
@@ -253,6 +254,7 @@ class CrossEncoderScorer(_HostScorer):
         self.params = params
         self.cfg = cfg
         self.pair_fn = pair_fn
+        attn_impl = resolve_attn_impl(attn_impl)
         self.pad_id = pad_id
         self.micro_batch = micro_batch
         self.len_buckets = tuple(sorted(len_buckets))
@@ -272,7 +274,7 @@ class CrossEncoderScorer(_HostScorer):
             self._n_traces += 1          # trace-time side effect
             return cross_encoder.score_tokens(
                 params, tokens, cfg, pad_id=pad_id, attn_impl=attn_impl,
-                flash_block=flash_block, flash_interpret=flash_interpret,
+                flash_block=flash_block,
             )
 
         self._jit_scored = jax.jit(scored)
@@ -330,6 +332,9 @@ class DeviceCEScorer:
     """
 
     device_resident = True
+    # pairs per compiled step of bulk_score: bounds the CE activations of an
+    # offline scoring sweep (~0.3M tokens at the 256-token bucket)
+    BULK_PAIRS = 1024
 
     def __init__(
         self,
@@ -341,9 +346,8 @@ class DeviceCEScorer:
         cls_id: int = 1,
         sep_id: int = 2,
         len_buckets: Tuple[int, ...] = (32, 64, 128, 256, 512),
-        attn_impl: str = "flash",
+        attn_impl: Optional[str] = None,
         flash_block: Tuple[int, int] = (128, 128),
-        flash_interpret: bool = True,
         record_pairs: bool = False,
     ):
         self.params = params
@@ -356,13 +360,14 @@ class DeviceCEScorer:
         self.cls_id = cls_id
         self.sep_id = sep_id
         self.len_buckets = tuple(sorted(len_buckets))
-        self.attn_impl = attn_impl
+        self.attn_impl = resolve_attn_impl(attn_impl)
         self.flash_block = flash_block
-        self.flash_interpret = flash_interpret
         self.record_pairs = record_pairs
         self.stats = ScorerStats()
         self.call_log: List[np.ndarray] = []
         self._n_traces = 0
+        self._bulk = jax.jit(self._bulk_impl)
+        self._bulk_sharded = {}          # mesh -> compiled sharded bulk
 
     # -- host side: once per request batch, before the round loop ----------
 
@@ -401,16 +406,69 @@ class DeviceCEScorer:
             cls_id=self.cls_id, sep_id=self.sep_id, pad_id=self.pad_id,
         )
 
-    def forward(self, flat_tokens) -> jax.Array:
-        """(M, bucket) pair rows -> (M,) CE scores, in the caller's trace."""
+    def _score_flat(self, flat_tokens) -> jax.Array:
         from ..models import cross_encoder
 
-        self._n_traces += 1              # trace-time side effect
         return cross_encoder.score_tokens(
             self.params, flat_tokens, self.cfg, pad_id=self.pad_id,
             attn_impl=self.attn_impl, flash_block=self.flash_block,
-            flash_interpret=self.flash_interpret,
         )
+
+    def forward(self, flat_tokens) -> jax.Array:
+        """(M, bucket) pair rows -> (M,) CE scores, in the caller's trace."""
+        self._n_traces += 1              # trace-time side effect
+        return self._score_flat(flat_tokens)
+
+    # -- offline bulk scoring (index build, brute-force reference) ---------
+
+    def bulk_score(self, query_ids, item_ids, mesh=None) -> jax.Array:
+        """Exact CE scores of every (query, item) pair -> (Q, N), on device.
+
+        The offline counterpart of in-trace scoring, with the
+        ``AnchorIndex.build`` bulk-scorer signature: queries are tokenized
+        on the host, items read from the scorer's token table, and pairs
+        scored ``BULK_PAIRS`` at a time under one compiled ``lax.map`` so
+        activations stay bounded whatever N is.  With ``mesh`` the items
+        split evenly over all of its devices, each scoring its share with
+        the same per-step shape; the result is gathered to the default
+        device either way.  Offline work is not serving cost: nothing is
+        counted in ``stats``.
+        """
+        if self.item_tokens is None:
+            raise ValueError("bulk_score reads the scorer's own token table: "
+                             "construct DeviceCEScorer with item_tokens=")
+        q_tok = self.tokenize_queries(query_ids)
+        rows = jnp.take(self.item_tokens, jnp.asarray(item_ids), axis=0)
+        if mesh is None:
+            return self._bulk(q_tok, rows)
+        from jax.sharding import PartitionSpec as P
+
+        n = rows.shape[0]
+        rows = jnp.pad(rows, ((0, -n % mesh.size), (0, 0)),
+                       constant_values=self.pad_id)
+        if mesh not in self._bulk_sharded:
+            axes = tuple(mesh.axis_names)
+            self._bulk_sharded[mesh] = jax.jit(jax.shard_map(
+                self._bulk_impl, mesh=mesh, in_specs=(P(), P(axes)),
+                out_specs=P(None, axes), check_vma=False,
+            ))
+        out = self._bulk_sharded[mesh](q_tok, rows)[:, :n]
+        return jax.device_put(out, q_tok.sharding)
+
+    def _bulk_impl(self, q_tok, rows):
+        qn, li = q_tok.shape[0], rows.shape[1]
+        n = rows.shape[0]
+        chunk = max(1, self.BULK_PAIRS // qn)           # items per step
+        rows = jnp.pad(rows, ((0, -n % chunk), (0, 0)), constant_values=self.pad_id)
+
+        def step(item_rows):                            # (chunk, Li)
+            pairs = self.build_pairs(
+                q_tok, jnp.broadcast_to(item_rows[None], (qn, chunk, li))
+            )
+            return self._score_flat(pairs.reshape(qn * chunk, -1)).reshape(qn, chunk)
+
+        out = jax.lax.map(step, rows.reshape(-1, chunk, li))  # (steps, Q, chunk)
+        return jnp.moveaxis(out, 0, 1).reshape(qn, -1)[:, :n].astype(jnp.float32)
 
     # -- measured accounting (numpy-only callback: mesh-legal) -------------
 

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from . import compression
-from ..compat import shard_map
 
 
 def make_hierarchical_grad_reduce(mesh: Mesh, grad_specs):
@@ -73,7 +72,7 @@ def make_hierarchical_grad_reduce(mesh: Mesh, grad_specs):
         return out, new_err
 
     def reduce_fn(grads, err):
-        return shard_map(
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(local_specs, local_specs),

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models import layers
-from ..compat import shard_map
 
 
 def _decode_core_body(
@@ -91,7 +90,7 @@ def make_decode_core(
     sspec = tuple(seq_axes)
     body = partial(_decode_core_body, seq_axes=sspec, local_len=local_len)
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

@@ -24,7 +24,6 @@ from typing import Callable, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from ..compat import shard_map
 
 
 def pipeline_forward(
@@ -78,7 +77,7 @@ def pipeline_forward(
         out = jax.lax.psum(out, pipe_axis) / 1.0  # ranks != last wrote zeros
         return out.reshape(x_local.shape)
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(pipe_axis), P()),

@@ -14,8 +14,8 @@ with a per-tile top-k so scores never leave VMEM:
     scores += noise[:, tile]                      (optional Gumbel input —
                                                    SoftMax sampling w/o
                                                    replacement, Kool 2019)
-    mask   = tile_ids ∈ anchor set (fused Alg. 3 line 8) ∧ tile_ids < n_valid
-             [∨ mask[:, tile] when a dense bool mask is passed instead]
+    drop   = mask[:, tile] (selected anchors, Alg. 3 line 8)
+             ∨ tile_ids >= n_valid
     per-tile top-k via k iterations of (max, argmax, suppress)
   outputs: (B, n_tiles, k) values + global indices.
 
@@ -23,12 +23,10 @@ The tiny (B, n_tiles·k) cross-tile merge happens in ops.py with one
 jax.lax.top_k — n_tiles·k ≪ N, so the HBM round-trip shrinks by ~T/k
 (e.g. 512/64 = 8x) and the GEMM output never hits HBM at all.
 
-Masking comes in two flavors: an anchor-id list (B, A) compared per tile
-(A ≪ N ids stay resident in VMEM — the right trade on TPU), or a dense
-(B, N) bool mask streamed tile-by-tile (O(B·T) per tile — the right trade
-for the CPU scan emulation in ops.py, and for engines that already maintain
-the ``selected`` mask).  ``n_valid`` suppresses padded item ids >= n_valid
-when R_anc's item axis is padded to a shardable multiple (pod meshes).
+Suppression is a dense (B, N) bool mask streamed tile by tile (O(B·T) per
+tile): the engine already maintains its ``selected`` mask, so no id list is
+compared in-kernel.  ``n_valid`` suppresses padded item ids >= n_valid when
+R_anc's item axis is padded to a shardable multiple (pod meshes).
 """
 
 from __future__ import annotations
@@ -38,9 +36,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from .quant import unpack_int4
+from ..backend import resolve_interpret
+from .quant import HIGHEST, unpack_int4
 
 NEG_INF = -1e30
 
@@ -71,10 +69,36 @@ def pad_to_tile(tile: int, r_anc, noise=None, mask=None, scales=None,
     return r_anc, noise, mask, scales, n_pad
 
 
+def check_compiled_payload(kernel: str, pack: int, interpret: bool) -> None:
+    """Refuse a payload the compiled TPU kernel cannot take, naming it.
+
+    Packed int4 lowers, but widening its nibbles in-kernel
+    (``quant.unpack_int4``'s repeat-interleave) needs several times the
+    scoped VMEM of a v5e core at every tile width; the Pallas interpreter
+    and the scan backend still run it on CPU."""
+    if pack == 2 and not interpret:
+        raise NotImplementedError(
+            f"{kernel}: int4 payloads do not compile for TPU (the in-kernel "
+            f"nibble unpack exceeds scoped VMEM); use int8 or fp8"
+        )
+
+
+def as_kernel_mask(mask: jax.Array) -> jax.Array:
+    """A (B, N) bool suppression mask as the int8 operand the kernels
+    stream (a TPU kernel takes no bool memory operand)."""
+    return mask.astype(jnp.int8)
+
+
+def _set_col(buf, i, col):
+    """``buf[:, i] = col`` as an iota-mask select (a scatter does not lower
+    in a TPU kernel)."""
+    lanes = jax.lax.broadcasted_iota(jnp.int32, buf.shape, 1)
+    return jnp.where(lanes == i, col[:, None], buf)
+
+
 def _approx_topk_kernel(
     e_q_ref,        # (B, k_q)
     r_anc_ref,      # (k_q, T) scores / int8 / fp8 — or (k_q, T/2) packed int4
-    anchors_ref,    # (B, A) int32 — already-selected anchor ids (global)
     *rest,          # [scales_ref (1,T)] [noise_ref (B,T)] [mask_ref (B,T)]
                     # vals_ref, idx_ref
     tile: int,
@@ -101,48 +125,48 @@ def _approx_topk_kernel(
         r = unpack_int4(r)                                 # (k_q, T) int8
     r = r.astype(jnp.float32)                              # (k_q, T)
     scores = jax.lax.dot_general(
-        e_q, r, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        e_q, r, (((1,), (0,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32,
     )                                                       # (B, T)
     if scales_ref is not None:
         scores = scores * scales_ref[...]                  # (1, T) broadcast
     if noise_ref is not None:
         scores = scores + noise_ref[...].astype(jnp.float32)
     b = scores.shape[0]
-    gids = ti * tile + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    valid = gids < n_items
-    # fused anchor masking (Alg. 3 line 8): suppress already-selected items
-    anchors = anchors_ref[...]                              # (B, A)
-    hit = (gids[:, :, None] == anchors[:, None, :]).any(axis=2)
+    cols = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    gids = ti * tile + cols
+    # suppress padded ids and the streamed mask (already-selected anchors,
+    # Alg. 3 line 8)
+    drop = gids >= n_items
     if mask_ref is not None:
-        hit = hit | mask_ref[...]
-    scores = jnp.where(valid & ~hit, scores, NEG_INF)
+        drop = drop | (mask_ref[...].astype(jnp.int32) != 0)
+    scores = jnp.where(drop, NEG_INF, scores)
 
     def take_max(i, carry):
         s, vals, idx = carry
         m = jnp.max(s, axis=1)                              # (B,)
-        am = jnp.argmax(s, axis=1).astype(jnp.int32)        # (B,)
-        vals = vals.at[:, i].set(m)
-        idx = idx.at[:, i].set(ti * tile + am)
+        # first column holding the max (argmax's tie rule)
+        am = jnp.min(jnp.where(s == m[:, None], cols, tile), axis=1)
+        vals = _set_col(vals, i, m)
+        idx = _set_col(idx, i, ti * tile + am)
         # suppress the winner
-        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(cols == am[:, None], NEG_INF, s)
         return s, vals, idx
 
     vals0 = jnp.full((b, k), NEG_INF, jnp.float32)
     idx0 = jnp.zeros((b, k), jnp.int32)
     _, vals, idx = jax.lax.fori_loop(0, k, take_max, (scores, vals0, idx0))
-    vals_ref[:, 0, :] = vals
-    idx_ref[:, 0, :] = idx
+    vals_ref[...] = vals
+    idx_ref[...] = idx
 
 
 def approx_topk_tiles(
     e_q: jax.Array,        # (B, k_q) f32
     r_anc: jax.Array,      # (k_q, N) scores — or quantized codes (pass scales)
-    anchors: jax.Array,    # (B, A) int32 — global ids to mask (pad with -1)
     k: int,
     *,
     tile: int = 512,
-    interpret: bool = False,
+    interpret: bool | None = None,
     noise: jax.Array | None = None,   # (B, N) additive noise (Gumbel sampling)
     mask: jax.Array | None = None,    # (B, N) bool — True = suppress
     n_valid: int | None = None,       # real item count when N is padded
@@ -151,6 +175,8 @@ def approx_topk_tiles(
     n_cols: int | None = None,        # logical N when r_anc is packed
 ):
     """Returns per-tile (vals (B, n_tiles, k), idx (B, n_tiles, k))."""
+    interpret = resolve_interpret(interpret)
+    check_compiled_payload("approx_topk_sweep", pack, interpret)
     b, k_q = e_q.shape
     n = r_anc.shape[1] * pack if n_cols is None else n_cols
     if pack > 1 and tile % pack:
@@ -169,28 +195,28 @@ def approx_topk_tiles(
     in_specs = [
         pl.BlockSpec((b, k_q), lambda ti: (0, 0)),
         pl.BlockSpec((k_q, tile // pack), lambda ti: (0, ti)),
-        pl.BlockSpec(anchors.shape, lambda ti: (0, 0)),
     ]
-    inputs = [e_q, r_anc, anchors]
+    inputs = [e_q, r_anc]
     if scales is not None:
         in_specs.append(pl.BlockSpec((1, tile), lambda ti: (0, ti)))
         inputs.append(scales[None, :])
-    for extra in (noise, mask):
+    for extra in (noise, None if mask is None else as_kernel_mask(mask)):
         if extra is not None:
             in_specs.append(pl.BlockSpec((b, tile), lambda ti: (0, ti)))
             inputs.append(extra)
+    # per-tile lists are (n_tiles, B, k) with the tile axis squeezed out of
+    # the block, so every block's minor two dims are the array's own
+    out_block = pl.BlockSpec((pl.Squeezed(), b, k), lambda ti: (ti, 0, 0))
     vals, idx = pl.pallas_call(
         kernel,
         grid=(n_tiles,),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((b, 1, k), lambda ti: (0, ti, 0)),
-            pl.BlockSpec((b, 1, k), lambda ti: (0, ti, 0)),
-        ],
+        out_specs=[out_block, out_block],
         out_shape=[
-            jax.ShapeDtypeStruct((b, n_tiles, k), jnp.float32),
-            jax.ShapeDtypeStruct((b, n_tiles, k), jnp.int32),
+            jax.ShapeDtypeStruct((n_tiles, b, k), jnp.float32),
+            jax.ShapeDtypeStruct((n_tiles, b, k), jnp.int32),
         ],
         interpret=interpret,
+        name="approx_topk_sweep",
     )(*inputs)
-    return vals, idx
+    return jnp.swapaxes(vals, 0, 1), jnp.swapaxes(idx, 0, 1)

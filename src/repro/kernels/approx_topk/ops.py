@@ -12,8 +12,9 @@ behavior (no (B, N) float score matrix is ever formed):
   per-step dispatch overhead; the scan compiles to one tight XLA loop) and
   doubles as an executable spec of the kernel.
 
-``impl='auto'`` picks ``scan`` when ``interpret`` is requested (CPU
-emulation) and the real kernel otherwise.
+``impl='auto'`` is the compiled kernel on a TPU and ``scan`` elsewhere;
+the platform decides (``kernels/backend.py``), and a TPU never runs the
+scan or the interpreter.
 
 **Quantized payloads.**  ``r_anc`` may be a
 :class:`~repro.kernels.approx_topk.quant.QuantizedRanc` (int8, packed
@@ -40,20 +41,19 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from ..backend import resolve_impl
 from .kernel import NEG_INF, approx_topk_tiles, pad_to_tile
-from .quant import QuantizedRanc, unpack_int4
+from .quant import HIGHEST, QuantizedRanc, unpack_int4
 
 
-def _scan_topk_tiles(e_q, r_anc, anchors, k, tile, noise, mask, n_valid,
+def _scan_topk_tiles(e_q, r_anc, k, tile, noise, mask, n_valid,
                      scales=None, pack=1, n_cols=None):
     """lax.scan tiled reference with kernel-identical tie-breaks.
 
     ``tile`` is rebalanced so the last tile carries at most n_tiles-1 padded
     columns (a literal tile multiple can waste up to a whole tile of GEMM
     work — 23% at N=10k, tile=4096); a modest unroll amortizes the scan's
-    per-step dispatch on CPU.  ``anchors=None`` skips the id-compare
-    entirely — callers that maintain a (B, N) selected mask pass that
-    instead (O(B·T) per tile vs O(B·T·A)).  ``scales`` (N,), when given, is
+    per-step dispatch on CPU.  ``scales`` (N,), when given, is
     the quantized payload's per-column dequant scale, applied to each tile's
     GEMM output (scale rebalancing is free: scales are per *column*, so the
     scan tile width need not match the payload's quantization tile).
@@ -80,7 +80,9 @@ def _scan_topk_tiles(e_q, r_anc, anchors, k, tile, noise, mask, n_valid,
         )
         if pack == 2:
             r_tile = unpack_int4(r_tile)
-        scores = e_q32 @ r_tile.astype(jnp.float32)            # (B, tile)
+        scores = jnp.matmul(
+            e_q32, r_tile.astype(jnp.float32), precision=HIGHEST
+        )                                                       # (B, tile)
         if scales is not None:
             scores = scores * jax.lax.dynamic_slice(
                 scales, (lo,), (tile,)
@@ -91,8 +93,6 @@ def _scan_topk_tiles(e_q, r_anc, anchors, k, tile, noise, mask, n_valid,
             ).astype(jnp.float32)
         gids = lo + arange_t
         keep = (gids < n_eff)[None, :]
-        if anchors is not None:
-            keep = keep & ~(gids[None, :, None] == anchors[:, None, :]).any(axis=2)
         if mask is not None:
             keep = keep & ~jax.lax.dynamic_slice(mask, (0, lo), (b, tile))
         scores = jnp.where(keep, scores, NEG_INF)
@@ -113,11 +113,10 @@ def _scan_topk_tiles(e_q, r_anc, anchors, k, tile, noise, mask, n_valid,
 def approx_topk_op(
     e_q,
     r_anc,
-    anchors,
     k: int,
     *,
     tile: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
     noise=None,
     mask=None,
     n_valid: int | None = None,
@@ -128,9 +127,8 @@ def approx_topk_op(
     ``r_anc`` is the (k_q, N) score matrix — fp32, bf16, or an int8
     :class:`QuantizedRanc` payload (dequantized tile-by-tile inside the
     kernel; see module docstring).
-    ``anchors`` (B, A) are suppressed item ids (pad with -1; None = none);
-    ``mask`` (B, N) bool additionally suppresses where True (cheaper than a
-    long anchor list when the caller already maintains a selected-mask).
+    ``mask`` (B, N) bool suppresses where True (the engine's selected
+    anchors).
     ``noise`` (B, N), when given, is added to the scores before the top-k —
     passing Gumbel noise makes this an exact sample without replacement from
     softmax(S_hat) (Kool et al. 2019) with S_hat never materialized.
@@ -143,23 +141,18 @@ def approx_topk_op(
     else:
         codes, scales = r_anc, None
         pack, n_cols = 1, None
-    if impl == "auto":
-        impl = "scan" if interpret else "pallas"
+    impl, interpret = resolve_impl(impl, interpret)
     if impl == "scan":
         vals, idx = _scan_topk_tiles(
-            e_q, codes, anchors, k, tile, noise, mask, n_valid,
+            e_q, codes, k, tile, noise, mask, n_valid,
             scales=scales, pack=pack, n_cols=n_cols,
         )
-    elif impl == "pallas":
-        if anchors is None:
-            anchors = jnp.full((e_q.shape[0], 1), -1, jnp.int32)
+    else:
         vals, idx = approx_topk_tiles(
-            e_q, codes, anchors, k, tile=tile, interpret=interpret,
+            e_q, codes, k, tile=tile, interpret=interpret,
             noise=noise, mask=mask, n_valid=n_valid, scales=scales,
             pack=pack, n_cols=n_cols,
         )
-    else:
-        raise ValueError(f"unknown impl '{impl}'")
     b, n_tiles, _ = vals.shape
     # merge: n_tiles*k ≪ N.  Tiles flatten in ascending order and lax.top_k
     # is index-stable, so equal values resolve to the lowest global id.

@@ -11,7 +11,7 @@ This module fuses the whole round into ONE payload sweep:
 
   grid = (n_item_tiles,); each step:
     scores  = e_q @ dequant(codes[:, tile])        (MXU, computed ONCE)
-    sample  = running top-k of scores + Gumbel     (anchor/mask suppressed)
+    sample  = running top-k of scores + Gumbel     (mask suppressed)
     prov    = running top-k of scores              (eligibility-masked)
 
 Round state — ``e_q`` and both running top-k accumulators — stays resident
@@ -26,8 +26,8 @@ plain fp32 XLA.
 
 Two interchangeable backends, mirroring ops.py:
 
-- ``pallas``: the persistent kernel above (``interpret=True`` runs it under
-  the Pallas interpreter on CPU);
+- ``pallas``: the persistent kernel above — what a TPU always runs
+  (``interpret=True`` runs it under the Pallas interpreter on CPU);
 - ``scan``: a lax.scan over item tiles carrying the running top-k lists —
   the fast CPU path and the executable spec.  When the caller passes a
   ``noise_key`` instead of a materialized (B, N) noise array, the scan
@@ -50,14 +50,17 @@ the staged path.
 
 from __future__ import annotations
 
-from functools import partial
+from functools import partial, reduce
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .kernel import NEG_INF, pad_to_tile
-from .quant import QuantizedRanc, unpack_int4
+from ..backend import resolve_impl, resolve_interpret
+from .kernel import (
+    NEG_INF, as_kernel_mask, check_compiled_payload, pad_to_tile,
+)
+from .quant import HIGHEST, QuantizedRanc, unpack_int4
 
 
 def _sampling():
@@ -94,7 +97,7 @@ def _merge_min_id(cv, ci, tv, ti, k):
 
 
 def _persistent_scan(
-    e_q, codes, scales, n, pack, k_sample, k_prov, anchors, mask, prov_mask,
+    e_q, codes, scales, n, pack, k_sample, k_prov, mask, prov_mask,
     noise, noise_key, row_offset, col_offset, n_valid, tile,
 ):
     b, k_q = e_q.shape
@@ -125,7 +128,9 @@ def _persistent_scan(
         )
         if pack == 2:
             r_tile = unpack_int4(r_tile)
-        gemm = e_q32 @ r_tile.astype(jnp.float32)              # (B, tile)
+        gemm = jnp.matmul(
+            e_q32, r_tile.astype(jnp.float32), precision=HIGHEST
+        )                                                       # (B, tile)
         if scales is not None:
             scale_t = jax.lax.dynamic_slice(scales, (lo,), (tile,))[None, :]
 
@@ -151,10 +156,6 @@ def _persistent_scan(
                     noise_key, b, tile, row_offset, col_offset + lo
                 )
             keep = base
-            if anchors is not None:
-                keep = keep & ~(
-                    gids[None, :, None] == anchors[:, None, :]
-                ).any(axis=2)
             if mask is not None:
                 keep = keep & ~jax.lax.dynamic_slice(mask, (0, lo), (b, tile))
             s = jnp.where(keep, s, NEG_INF)
@@ -202,40 +203,50 @@ def _persistent_scan(
     return carry
 
 
-def _select_min_id(buf_v, buf_i, k):
-    """k iterations of (max value, min item id) selection over a buffer.
+def _select_min_id(bufs, k):
+    """k iterations of (max value, min item id) selection over the union of
+    ``bufs`` — a list of (values (B, w), ids (B, w)) pairs.
 
     Explicitly encodes the ascending-item-id tie rule, so the result is
     independent of buffer order — in particular of where carried entries
-    sit relative to the current tile's entries.
+    sit relative to the current tile's entries.  The buffers are scanned
+    side by side rather than concatenated (a lane-unaligned concatenate
+    does not lower in a TPU kernel), and each pick is written through an
+    iota mask (nor does a scatter).
     """
-    b = buf_v.shape[0]
+    b = bufs[0][0].shape[0]
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (b, k), 1)
 
     def take(i, carry):
-        v, idx, bv, bi = carry
-        m = jnp.max(bv, axis=1)                                # (B,)
-        is_max = bv == m[:, None]
-        g = jnp.min(jnp.where(is_max, bi, _SENTINEL_ID), axis=1)
-        v = v.at[:, i].set(m)
-        idx = idx.at[:, i].set(g)
-        sup = is_max & (bi == g[:, None])
-        bv = jnp.where(sup, NEG_INF, bv)
-        bi = jnp.where(sup, _SENTINEL_ID, bi)
-        return v, idx, bv, bi
+        v, idx, bufs = carry
+        m = reduce(
+            jnp.maximum, [jnp.max(bv, axis=1) for bv, _ in bufs]
+        )                                                      # (B,)
+        g = reduce(jnp.minimum, [
+            jnp.min(jnp.where(bv == m[:, None], bi, _SENTINEL_ID), axis=1)
+            for bv, bi in bufs
+        ])                                                     # (B,)
+        v = jnp.where(lanes == i, m[:, None], v)
+        idx = jnp.where(lanes == i, g[:, None], idx)
+        out = []
+        for bv, bi in bufs:
+            sup = (bv == m[:, None]) & (bi == g[:, None])
+            out.append((jnp.where(sup, NEG_INF, bv),
+                        jnp.where(sup, _SENTINEL_ID, bi)))
+        return v, idx, out
 
     v0 = jnp.full((b, k), NEG_INF, jnp.float32)
     i0 = jnp.zeros((b, k), jnp.int32)
-    v, idx, _, _ = jax.lax.fori_loop(0, k, take, (v0, i0, buf_v, buf_i))
+    v, idx, _ = jax.lax.fori_loop(0, k, take, (v0, i0, list(bufs)))
     return v, idx
 
 
 def _persistent_kernel(
     e_q_ref, codes_ref, *rest,
     tile, k_sample, k_prov, n_items, pack,
-    has_anchors, has_scales, has_noise, has_mask, has_prov_mask,
+    has_scales, has_noise, has_mask, has_prov_mask,
 ):
     it = iter(rest)
-    anchors_ref = next(it) if has_anchors else None
     scales_ref = next(it) if has_scales else None
     noise_ref = next(it) if has_noise else None
     mask_ref = next(it) if has_mask else None
@@ -248,7 +259,7 @@ def _persistent_kernel(
         r = unpack_int4(r)
     gemm = jax.lax.dot_general(
         e_q, r.astype(jnp.float32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        precision=HIGHEST, preferred_element_type=jnp.float32,
     )                                                           # (B, T)
 
     def scaled():
@@ -266,9 +277,7 @@ def _persistent_kernel(
         # accumulator in VMEM for the whole grid.
         cv = jnp.where(ti == 0, NEG_INF, v_ref[...])
         ci = jnp.where(ti == 0, _SENTINEL_ID, i_ref[...])
-        buf_v = jnp.concatenate([cv, s], axis=1)                # (B, k+T)
-        buf_i = jnp.concatenate([ci, gids], axis=1)
-        v, idx = _select_min_id(buf_v, buf_i, k)
+        v, idx = _select_min_id([(cv, ci), (s, gids)], k)
         v_ref[...] = v
         i_ref[...] = idx
 
@@ -278,23 +287,22 @@ def _persistent_kernel(
         if noise_ref is not None:
             s = s + noise_ref[...].astype(jnp.float32)
         keep = base
-        if anchors_ref is not None:
-            anchors = anchors_ref[...]
-            keep = keep & ~(gids[:, :, None] == anchors[:, None, :]).any(axis=2)
         if mask_ref is not None:
-            keep = keep & ~mask_ref[...]
+            keep = keep & (mask_ref[...].astype(jnp.int32) == 0)
         run(jnp.where(keep, s, NEG_INF), next(o), next(o), k_sample)
     if k_prov is not None:
         keep = base
         if prov_mask_ref is not None:
-            keep = keep & ~prov_mask_ref[...]
+            keep = keep & (prov_mask_ref[...].astype(jnp.int32) == 0)
         run(jnp.where(keep, scaled(), NEG_INF), next(o), next(o), k_prov)
 
 
 def _persistent_pallas(
-    e_q, codes, scales, n, pack, k_sample, k_prov, anchors, mask, prov_mask,
+    e_q, codes, scales, n, pack, k_sample, k_prov, mask, prov_mask,
     noise, n_valid, tile, interpret,
 ):
+    interpret = resolve_interpret(interpret)
+    check_compiled_payload("persistent_round_sweep", pack, interpret)
     b, k_q = e_q.shape
     if pack > 1 and tile % pack:
         tile += pack - tile % pack
@@ -309,7 +317,7 @@ def _persistent_pallas(
     kernel = partial(
         _persistent_kernel, tile=tile, k_sample=k_sample, k_prov=k_prov,
         n_items=n if n_valid is None else min(n_valid, n), pack=pack,
-        has_anchors=anchors is not None, has_scales=scales is not None,
+        has_scales=scales is not None,
         has_noise=noise is not None, has_mask=mask is not None,
         has_prov_mask=prov_mask is not None,
     )
@@ -318,13 +326,14 @@ def _persistent_pallas(
         pl.BlockSpec((k_q, tile // pack), lambda ti: (0, ti)),
     ]
     inputs = [e_q, codes]
-    if anchors is not None:
-        in_specs.append(pl.BlockSpec(anchors.shape, lambda ti: (0, 0)))
-        inputs.append(anchors)
     if scales is not None:
         in_specs.append(pl.BlockSpec((1, tile), lambda ti: (0, ti)))
         inputs.append(scales[None, :])
-    for extra in (noise, mask, prov_mask):
+    for extra in (
+        noise,
+        None if mask is None else as_kernel_mask(mask),
+        None if prov_mask is None else as_kernel_mask(prov_mask),
+    ):
         if extra is not None:
             in_specs.append(pl.BlockSpec((b, tile), lambda ti: (0, ti)))
             inputs.append(extra)
@@ -346,6 +355,7 @@ def _persistent_pallas(
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="persistent_round_sweep",
     )(*inputs)
     pairs = [(outs[i], outs[i + 1]) for i in range(0, len(outs), 2)]
     return tuple(pairs)
@@ -361,7 +371,6 @@ def persistent_round_op(
     *,
     k_sample: int | None = None,
     k_prov: int | None = None,
-    anchors=None,
     mask=None,
     prov_mask=None,
     noise=None,
@@ -370,7 +379,7 @@ def persistent_round_op(
     col_offset=0,
     n_valid: int | None = None,
     tile: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
     impl: str = "auto",
 ):
     """One fused payload sweep -> sampled top-k and/or provisional top-k.
@@ -379,10 +388,10 @@ def persistent_round_op(
     idx (B,k))`` pair or ``None`` if its k was not requested.  Bit-identical
     to the corresponding staged calls:
 
-    - ``sample`` == ``approx_topk_op(e_q, r_anc, anchors, k_sample,
-      noise=noise, mask=mask, n_valid=n_valid)``
-    - ``prov``   == ``approx_topk_op(e_q, r_anc, None, k_prov,
-      mask=prov_mask, n_valid=n_valid)``
+    - ``sample`` == ``approx_topk_op(e_q, r_anc, k_sample, noise=noise,
+      mask=mask, n_valid=n_valid)``
+    - ``prov``   == ``approx_topk_op(e_q, r_anc, k_prov, mask=prov_mask,
+      n_valid=n_valid)``
 
     but streams the payload from HBM once instead of twice.  ``noise_key``
     (+ global ``row_offset``/``col_offset``) may replace a materialized
@@ -398,24 +407,21 @@ def persistent_round_op(
         pack, n = r_anc.packing, r_anc.shape[1]
     else:
         codes, scales, pack, n = r_anc, None, 1, r_anc.shape[1]
-    if impl == "auto":
-        impl = "scan" if interpret else "pallas"
+    impl, interpret = resolve_impl(impl, interpret)
     if impl == "scan":
         pairs = _persistent_scan(
-            e_q, codes, scales, n, pack, k_sample, k_prov, anchors, mask,
-            prov_mask, noise, noise_key, row_offset, col_offset, n_valid, tile,
+            e_q, codes, scales, n, pack, k_sample, k_prov, mask, prov_mask,
+            noise, noise_key, row_offset, col_offset, n_valid, tile,
         )
-    elif impl == "pallas":
+    else:
         if noise is None and noise_key is not None:
             noise = _sampling().blocked_gumbel(
                 noise_key, e_q.shape[0], n, row_offset, col_offset
             )
         pairs = _persistent_pallas(
-            e_q, codes, scales, n, pack, k_sample, k_prov, anchors, mask,
-            prov_mask, noise, n_valid, tile, interpret,
+            e_q, codes, scales, n, pack, k_sample, k_prov, mask, prov_mask,
+            noise, n_valid, tile, interpret,
         )
-    else:
-        raise ValueError(f"unknown impl '{impl}'")
     pairs = list(pairs)
     sample = pairs.pop(0) if k_sample is not None else None
     prov = pairs.pop(0) if k_prov is not None else None

@@ -50,6 +50,11 @@ PAYLOAD_DTYPES = ("float32", "bfloat16", "int8", "int4", "fp8")
 CODE_DTYPES = ("int8", "int4", "fp8")
 DEFAULT_TILE = 512
 
+# the approximate-score contraction e_q @ R_anc in fp32 on every backend
+# (a TPU's default would round both operands to bf16); every path that
+# computes S_hat — dense, scan and kernel — uses it, so their scores agree
+HIGHEST = jax.lax.Precision.HIGHEST
+
 _QMAX = {"int8": 127.0, "int4": 7.0, "fp8": 448.0}
 # real storage bytes per column per k_q row (scales add 4 / tile per column)
 CODE_BYTES_PER_COL = {"int8": 1.0, "int4": 0.5, "fp8": 1.0}
@@ -268,9 +273,16 @@ def matmul(e_q: jax.Array, r_anc) -> jax.Array:
     same factoring the kernels use, so dense and fused scores agree.
     """
     if isinstance(r_anc, QuantizedRanc):
-        s = e_q.astype(jnp.float32) @ unpacked_codes(r_anc).astype(jnp.float32)
+        s = jnp.matmul(
+            e_q.astype(jnp.float32),
+            unpacked_codes(r_anc).astype(jnp.float32),
+            precision=HIGHEST,
+        )
         return s * r_anc.col_scales()[None, :]
-    return e_q.astype(jnp.float32) @ jnp.asarray(r_anc).astype(jnp.float32)
+    return jnp.matmul(
+        e_q.astype(jnp.float32), jnp.asarray(r_anc).astype(jnp.float32),
+        precision=HIGHEST,
+    )
 
 
 def take_columns(r_anc, pos: jax.Array) -> jax.Array:

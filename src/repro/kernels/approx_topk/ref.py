@@ -1,4 +1,4 @@
-"""Pure-jnp oracle: materialize S_hat, mask anchors, full top-k.
+"""Pure-jnp oracle: materialize S_hat, apply the mask, full top-k.
 
 Accepts the same payload types as the fused op (fp32 / bf16 arrays or an
 int8 :class:`QuantizedRanc`), dequantizing with the same per-column scale
@@ -19,7 +19,6 @@ NEG_INF = -1e30
 def approx_topk_reference(
     e_q: jax.Array,       # (B, k_q)
     r_anc: jax.Array,     # (k_q, N) — or an int8 QuantizedRanc payload
-    anchors: jax.Array,   # (B, A) global ids to mask (-1 = unused)
     k: int,
     noise: jax.Array | None = None,   # (B, N) additive noise
     mask: jax.Array | None = None,    # (B, N) bool — True = suppress
@@ -34,7 +33,7 @@ def approx_topk_reference(
         scores = scores + noise.astype(jnp.float32)
     n = scores.shape[1]
     ids = jnp.arange(n)
-    hit = (ids[None, :, None] == anchors[:, None, :]).any(axis=2)
+    hit = jnp.zeros(scores.shape, bool)
     if mask is not None:
         hit = hit | mask
     if n_valid is not None:

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..backend import resolve_interpret
+
 
 def _bag_kernel(ids_ref, table_row_ref, out_ref, acc_ref, *, n_hot: int, mode: str):
     h = pl.program_id(1)
@@ -47,7 +49,7 @@ def embedding_bag(
     hot_ids: jax.Array,   # (B, H) int32
     *,
     mode: str = "sum",
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Fixed-width multi-hot bag lookup -> (B, dim)."""
     b, n_hot = hot_ids.shape
@@ -67,5 +69,5 @@ def embedding_bag(
         functools.partial(_bag_kernel, n_hot=n_hot, mode=mode),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, dim), table.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(hot_ids, table)

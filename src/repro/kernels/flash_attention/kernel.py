@@ -20,25 +20,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..backend import resolve_interpret
+
 NEG_INF = -1e30
 
 
 def _flash_kernel(
-    *refs,                        # [lens_ref,] q, k, v, o, m, l, acc
+    lens_ref,                     # (B,) int32 SMEM (scalar prefetch)
+    q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+    *,
     scale: float,
     causal: bool,
     block_q: int,
     block_k: int,
-    kv_len: int,
+    n_heads: int,
     q_offset: int,
-    has_lens: bool = False,
 ):
-    if has_lens:
-        # (1, 1) SMEM per-(batch*head) valid KV length — variable-length
-        # sequences packed into one padded bucket (cross-encoder scoring)
-        lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
+    # per-example valid KV length — variable-length sequences packed into
+    # one padded bucket (cross-encoder scoring); grid row bh = b*H + h
+    kv_limit = lens_ref[pl.program_id(0) // n_heads]
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     n_k = pl.num_programs(2)
@@ -65,8 +65,7 @@ def _flash_kernel(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale                                            # (bq, bk)
         kv_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        limit = jnp.minimum(kv_len, lens_ref[0, 0]) if has_lens else kv_len
-        mask = kv_pos < limit
+        mask = kv_pos < kv_limit
         if causal:
             q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             mask &= kv_pos <= q_pos
@@ -96,7 +95,7 @@ def flash_attention(
     causal: bool = True,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = False,
+    interpret: bool | None = None,
     kv_lens: jax.Array | None = None,   # (B,) int32 valid KV length / example
 ) -> jax.Array:
     """pallas_call wrapper; returns (B, Lq, H, hd).
@@ -104,7 +103,9 @@ def flash_attention(
     ``kv_lens`` masks each example's trailing padding (keys at positions >=
     kv_lens[b] never contribute): how variable-length query-item pairs are
     scored through one static padded bucket shape without retracing.  The
-    lengths ride in SMEM per (batch*head) grid row — no (B, Lk) mask in HBM.
+    (B,) lengths are a scalar-prefetch operand, resident in SMEM for the
+    whole grid — no (B, Lk) mask in HBM.  Without ``kv_lens`` every key of
+    the unpadded length is valid.
     """
     b, lq, h, hd = q.shape
     _, lk, n_kv, _ = k.shape
@@ -127,50 +128,45 @@ def flash_attention(
     kh = k.transpose(0, 2, 1, 3).reshape(b * n_kv, lk_pad, hd)
     vh = v.transpose(0, 2, 1, 3).reshape(b * n_kv, lk_pad, hd)
 
-    grid = (b * h, lq_pad // block_q, lk_pad // block_k)
+    if kv_lens is None:
+        lens = jnp.full((b,), lk, jnp.int32)
+    else:
+        lens = jnp.minimum(kv_lens.astype(jnp.int32), lk)
 
-    def q_map(bh, qi, ki):
+    def q_map(bh, qi, ki, lens_ref):
         return (bh, qi, 0)
 
-    def kv_map(bh, qi, ki):
+    def kv_map(bh, qi, ki, lens_ref):
         # fold query head onto its KV group: bh = bi*H + hi
         bi = bh // h
         hi = bh % h
         return (bi * n_kv + hi // q_per_kv, ki, 0)
 
-    in_specs = [
-        pl.BlockSpec((1, block_q, hd), q_map),
-        pl.BlockSpec((1, block_k, hd), kv_map),
-        pl.BlockSpec((1, block_k, hd), kv_map),
-    ]
-    operands = [qh, kh, vh]
-    if kv_lens is not None:
-        lens_bh = jnp.repeat(kv_lens.astype(jnp.int32), h)[:, None]  # (B*H, 1)
-        in_specs.insert(
-            0,
-            pl.BlockSpec(
-                (1, 1), lambda bh, qi, ki: (bh, 0), memory_space=pltpu.SMEM
-            ),
-        )
-        operands.insert(0, lens_bh)
-
-    out = pl.pallas_call(
-        functools.partial(
-            _flash_kernel,
-            scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, kv_len=lk, q_offset=q_offset,
-            has_lens=kv_lens is not None,
-        ),
-        grid=grid,
-        in_specs=in_specs,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b * h, lq_pad // block_q, lk_pad // block_k),
+        in_specs=[
+            pl.BlockSpec((1, block_q, hd), q_map),
+            pl.BlockSpec((1, block_k, hd), kv_map),
+            pl.BlockSpec((1, block_k, hd), kv_map),
+        ],
         out_specs=pl.BlockSpec((1, block_q, hd), q_map),
-        out_shape=jax.ShapeDtypeStruct((b * h, lq_pad, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        interpret=interpret,
-    )(*operands)
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _flash_kernel,
+            scale=scale, causal=causal,
+            block_q=block_q, block_k=block_k, n_heads=h, q_offset=q_offset,
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b * h, lq_pad, hd), q.dtype),
+        interpret=resolve_interpret(interpret),
+        name="flash_attention",
+    )(lens, qh, kh, vh)
     out = out.reshape(b, h, lq_pad, hd).transpose(0, 2, 1, 3)
     return out[:, :lq]

@@ -1,7 +1,7 @@
 """jit'd public wrapper for the flash attention kernel.
 
-``interpret=True`` executes the kernel body on CPU (how this container
-validates it); on a real TPU the same call lowers to Mosaic.
+``interpret=None`` lets the platform decide (``kernels/backend.py``): the
+compiled Mosaic kernel on a TPU, the Pallas interpreter elsewhere.
 """
 
 from __future__ import annotations
@@ -15,7 +15,7 @@ from .kernel import flash_attention
 
 @partial(jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret"))
 def flash_attention_op(
-    q, k, v, *, causal=True, block_q=128, block_k=128, interpret=True, kv_lens=None
+    q, k, v, *, causal=True, block_q=128, block_k=128, interpret=None, kv_lens=None
 ):
     return flash_attention(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,

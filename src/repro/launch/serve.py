@@ -29,6 +29,15 @@ CLI:  PYTHONPATH=src python -m repro.launch.serve --requests 64 \
           [--payload-dtype {float32,bfloat16,int8,int4,fp8}] \
           [--round-kernel {staged,persistent}] [--mesh DATAxITEMS]
 
+``--scorer real-ce`` serves the CE_TINY transformer cross-encoder at its
+registry widths (random weights from ``--seed``) over a ZESHEL-like corpus
+(``--n-items``, ``--item-len``, ``--query-len``): the AnchorIndex is built
+from ``--anchor-queries`` x n_items CE scores computed on device, and every
+request is scored *device-resident* — pair assembly and the CE forward run
+inside the engine's one compiled program (``DeviceCEScorer`` over the
+index's token table), on one device as under ``--mesh``.  ``chip_smoke.py``
+at the repository root runs this path on a TPU.
+
 ``--first-stage de|bm25`` serves the multi-stage hybrid: a dual-encoder or
 BM25 generator proposes a per-query shortlist and the ADACUR search is
 restricted to those candidates via the engine's ``eligible`` mask (the
@@ -53,11 +62,12 @@ and the device-resident CE never leaves the device.
 from __future__ import annotations
 
 import argparse
+import functools
 import os
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Callable, List, Optional, Union
+from typing import Any, Callable, List, Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -72,6 +82,8 @@ from ..core.engine import (
 )
 from ..core.index import AnchorIndex, clear_build_checkpoints
 from ..core.scorer import ScorerStats, scorer_stats
+from ..kernels.backend import on_tpu
+from .compile_cache import enable_compile_cache
 
 
 @dataclass
@@ -352,7 +364,8 @@ def make_retriever(
     raise ValueError(f"unknown retriever '{kind}' (adacur|anncur|rerank)")
 
 
-def main() -> None:
+def build_arg_parser() -> argparse.ArgumentParser:
+    """The serving CLI; ``chip_smoke.py`` drives the same path through it."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=64)
     ap.add_argument("--n-items", type=int, default=10000)
@@ -398,11 +411,27 @@ def main() -> None:
                          "items axis shards the index payload, the data axis "
                          "shards request batches; the full engine runs as one "
                          "shard_map program (device count must match)")
-    args = ap.parse_args()
+    ap.add_argument("--seed", type=int, default=0,
+                    help="real-ce: seed of the corpus and the CE's random "
+                         "weights")
+    ap.add_argument("--item-len", type=int, default=128,
+                    help="real-ce: tokens per entity description")
+    ap.add_argument("--query-len", type=int, default=64,
+                    help="real-ce: tokens per mention (pairs are "
+                         "item_len + query_len + 3 tokens, bucketed)")
+    ap.add_argument("--anchor-queries", type=int, default=100,
+                    help="real-ce: k_q anchor queries the index is built "
+                         "from (k_q x n_items CE calls offline)")
+    return ap
+
+
+def main() -> None:
+    args = build_arg_parser().parse_args()
+    enable_compile_cache()
 
     from ..data.synthetic import make_synthetic_ce
 
-    if (args.scorer == "real-ce" and not args.mesh
+    if (args.scorer == "real-ce" and args.cache
             and len(os.sched_getaffinity(0)) < 2):
         # single-core host: the async CPU client has one execute thread, so
         # the host CE callback's nested jit would self-block (the
@@ -411,10 +440,6 @@ def main() -> None:
         jax.config.update("jax_cpu_enable_async_dispatch", False)
 
     if args.scorer == "real-ce":
-        # with --mesh the CE runs device-resident inside the shard_map
-        # program (DeviceCEScorer + the index token table); capability
-        # detection lives in make_sharded_engine, which rejects any scorer
-        # whose host callback would launch nested device compute
         _serve_real_ce(args)
         return
 
@@ -444,12 +469,7 @@ def main() -> None:
             clear_build_checkpoints(args.index_path)
             print(f"saved AnchorIndex to {args.index_path}")
 
-    cfg = AdaCURConfig(
-        k_anchor=args.budget // 2, n_rounds=args.rounds, budget_ce=args.budget,
-        strategy="topk", k_retrieve=100, loop_mode="fori",
-        use_fused_topk=args.fused, payload_dtype=args.payload_dtype,
-        round_kernel=args.round_kernel,
-    )
+    cfg = serving_config(args)
     if args.payload_dtype != "float32":
         fp32_bytes = index.payload_nbytes
         index = index.quantize(args.payload_dtype, tile=cfg.payload_tile)
@@ -507,12 +527,12 @@ def main() -> None:
     svc = AdaCURService(
         retriever=retriever, max_batch=args.batch, candidate_fn=candidate_fn
     )
-    _drive(svc, args, cfg, brute_n=args.n_items)
+    exit_if_all_failed(drive_requests(svc, args, cfg, brute_n=args.n_items))
 
 
-def _shard_for_serving(index: AnchorIndex, args) -> AnchorIndex:
-    """``--mesh DxI`` -> place the index over a (data x items) mesh; the
-    retriever then auto-binds the SPMD engine (engine.make_sharded_engine)."""
+def serving_mesh(args):
+    """The (data x items) mesh ``--mesh DxI`` asks for, checked against the
+    visible devices and the batch."""
     from .mesh import make_serving_mesh
 
     try:
@@ -531,15 +551,34 @@ def _shard_for_serving(index: AnchorIndex, args) -> AnchorIndex:
             f"--batch {args.batch} must divide into the service's batch "
             f"buckets over {d} data shards (make it a multiple of {4 * d})"
         )
-    mesh = make_serving_mesh(d, i)
+    return make_serving_mesh(d, i)
+
+
+def _shard_for_serving(index: AnchorIndex, args) -> AnchorIndex:
+    """``--mesh DxI`` -> place the index over a (data x items) mesh; the
+    retriever then auto-binds the SPMD engine (engine.make_sharded_engine)."""
+    mesh = serving_mesh(args)
+    i = mesh.shape["items"]
     print(f"sharding index over mesh {dict(mesh.shape)} "
           f"(payload per item-shard ~{index.payload_nbytes // i / 1e6:.1f} MB)")
     return index.shard(mesh)
 
 
-def _drive(svc: AdaCURService, args, cfg: AdaCURConfig,
-           qid_range=(500, 600), label: Optional[str] = None,
-           brute_n: Optional[int] = None) -> None:
+def serving_config(args) -> AdaCURConfig:
+    """The engine configuration the CLI serves with."""
+    return AdaCURConfig(
+        k_anchor=args.budget // 2, n_rounds=args.rounds, budget_ce=args.budget,
+        strategy="topk", k_retrieve=50 if args.scorer == "real-ce" else 100,
+        loop_mode="fori", use_fused_topk=args.fused,
+        payload_dtype=args.payload_dtype, round_kernel=args.round_kernel,
+    )
+
+
+def drive_requests(svc: AdaCURService, args, cfg: AdaCURConfig,
+                   qid_range=(500, 600), label: Optional[str] = None,
+                   brute_n: Optional[int] = None) -> List[RetrievalResponse]:
+    """Submit ``args.requests`` random queries through the event loop;
+    prints p50/p99 latency and returns the responses in serving order."""
     served = []
     rng = np.random.default_rng(0)
     for i in range(args.requests):
@@ -564,88 +603,151 @@ def _drive(svc: AdaCURService, args, cfg: AdaCURConfig,
             f"measured: {stats.ce_calls} CE calls, {stats.cache_hits} cache "
             f"hits ({stats.cache_size} resident pairs)"
         )
+    return served
 
 
-def _serve_real_ce(args) -> None:
-    """End-to-end serving with the REAL transformer cross-encoder: offline
-    index built by the bulk CE path, online scoring through the bucketed
-    flash-attention CrossEncoderScorer (+ optional pair cache) — or, under
-    ``--mesh``, through the device-resident DeviceCEScorer stage of the
-    SPMD engine (the index carries the corpus token table)."""
-    from ..configs.base import replace as cfg_replace
+def exit_if_all_failed(served: List[RetrievalResponse]) -> None:
+    """A run whose every response is an error exits non-zero, naming the
+    first error (a service answers failures as responses, so nothing else
+    would)."""
+    errors = [r for r in served if r.status != "ok"]
+    if served and len(errors) == len(served):
+        raise SystemExit(
+            f"all {len(served)} responses were errors; first: {errors[0].error}"
+        )
+
+
+# served (non-anchor) queries of the real-CE corpus
+REAL_CE_SERVE_QUERIES = 100
+
+
+@dataclass
+class RealCEStack:
+    """What real-CE serving is built on: the corpus, the CE and the index.
+
+    Built once (the offline side); every serving configuration over it is
+    :func:`real_ce_service`."""
+
+    ds: Any                    # data.synthetic.ZeshelLikeDataset
+    lm_cfg: Any                # configs.base.LMConfig
+    params: Any
+    scorer: Any                # core.scorer.DeviceCEScorer
+    index: AnchorIndex         # carries the corpus token table
+    build_s: float             # wall seconds of the index build
+
+    @property
+    def serve_qids(self):
+        k_q = self.index.k_q
+        return (k_q, k_q + REAL_CE_SERVE_QUERIES)
+
+
+def build_real_ce_stack(args) -> RealCEStack:
+    """ZESHEL-like corpus + the CE_TINY cross-encoder at its registry widths
+    (random weights from ``--seed``) + an AnchorIndex whose k_q x N anchor
+    scores the CE itself computes, on device."""
     from ..configs.registry import CE_TINY
-    from ..core.scorer import CachingScorer, CrossEncoderScorer, DeviceCEScorer
+    from ..core.scorer import DeviceCEScorer
     from ..data.synthetic import make_zeshel_like
     from ..models import cross_encoder
 
-    if args.mesh and args.cache:
+    k_q = args.anchor_queries
+    print(f"building ZESHEL-like corpus (|I|={args.n_items}, item_len="
+          f"{args.item_len}, query_len={args.query_len}) + {CE_TINY.name} CE "
+          f"({CE_TINY.n_layers} layers, d_model {CE_TINY.d_model}, "
+          f"{CE_TINY.dtype})...")
+    ds = make_zeshel_like(
+        args.seed, n_items=args.n_items,
+        n_queries=k_q + REAL_CE_SERVE_QUERIES, vocab=CE_TINY.vocab_size,
+        item_len=args.item_len, query_len=args.query_len,
+    )
+    params, _ = cross_encoder.init_cross_encoder(
+        jax.random.PRNGKey(args.seed), CE_TINY
+    )
+    scorer = DeviceCEScorer(
+        params, CE_TINY, query_token_fn=lambda q: ds.query_tokens[q],
+        item_tokens=ds.item_tokens,
+    )
+    # under --mesh every device scores its share of the items
+    mesh = serving_mesh(args) if args.mesh else None
+    where = f"over {mesh.size} devices" if mesh else "on device"
+    print(f"building AnchorIndex from the CE itself ({k_q} x {args.n_items} "
+          f"pairs, {where})...")
+    t0 = time.perf_counter()
+    index = AnchorIndex.build(
+        functools.partial(scorer.bulk_score, mesh=mesh), jnp.arange(k_q),
+        jnp.arange(args.n_items), block_rows=k_q,
+    )
+    build_s = time.perf_counter() - t0
+    return RealCEStack(ds, CE_TINY, params, scorer,
+                       index.with_item_tokens(ds.item_tokens), build_s)
+
+
+def real_ce_service(args, stack: RealCEStack):
+    """(service, config) serving ``stack`` as the CLI arguments say.
+
+    The CE scores device-resident (DeviceCEScorer over the index's token
+    table) — on one device and under ``--mesh`` alike.  ``--cache`` instead
+    scores through the host-callback CrossEncoderScorer behind the pair
+    cache (single device only)."""
+    from ..core.scorer import CachingScorer, CrossEncoderScorer
+
+    refuse_real_ce_cache(args)
+    cfg = serving_config(args)
+    index = stack.index
+    if args.cache:
+        scorer = CachingScorer(CrossEncoderScorer(
+            stack.params, stack.lm_cfg, stack.ds.pair_tokens, micro_batch=64
+        ))
+    else:
+        scorer = stack.scorer
+    if args.mesh:
+        index = _shard_for_serving(index, args)
+    retriever = make_retriever(args.retriever, index, scorer, cfg)
+    return AdaCURService(retriever=retriever, max_batch=args.batch), cfg
+
+
+def refuse_real_ce_cache(args) -> None:
+    """``--cache`` serves the real CE from a host callback; refuse the
+    combinations where that cannot work, before anything is built."""
+    if not args.cache:
+        return
+    if args.mesh:
         raise SystemExit(
             "--cache intercepts host-callback scorers; under --mesh the real "
             "CE scores device-resident inside the shard_map program and its "
             "pairs never cross the host boundary — drop --cache"
         )
-
-    n_items = min(args.n_items, 500)       # CE-scored corpus: keep CPU-friendly
-    n_anchor_q, n_serve_q = 100, 100
-    print(f"building ZESHEL-like corpus (|I|={n_items}) + tiny transformer CE...")
-    ds = make_zeshel_like(0, n_items=n_items, n_queries=n_anchor_q + n_serve_q,
-                          item_len=24, query_len=16)
-    lm_cfg = cfg_replace(
-        CE_TINY, n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, head_dim=16,
-        d_ff=128, vocab_size=ds.vocab_size, dtype="float32", remat=False,
-    )
-    params, _ = cross_encoder.init_cross_encoder(jax.random.PRNGKey(0), lm_cfg)
-    scorer = CrossEncoderScorer(
-        params, lm_cfg, ds.pair_tokens, micro_batch=64, flash_block=(64, 64)
-    )
-    if args.cache:
-        scorer = CachingScorer(scorer)
-
-    print("building AnchorIndex from the CE itself (block-streamed)...")
-
-    def bulk(q_ids, item_ids):
-        q = np.asarray(q_ids)
-        items = np.tile(np.asarray(item_ids), (len(q), 1))
-        inner = scorer.inner if args.cache else scorer
-        return jnp.asarray(inner._host(q, items))
-
-    index = AnchorIndex.build(
-        bulk, jnp.arange(n_anchor_q), jnp.arange(n_items), block_rows=32,
-    )
-    scorer.reset_stats()      # offline-build calls are not serving cost
-    cfg = AdaCURConfig(
-        k_anchor=args.budget // 2, n_rounds=args.rounds, budget_ce=args.budget,
-        strategy="topk", k_retrieve=50, loop_mode="fori",
-        use_fused_topk=args.fused, payload_dtype=args.payload_dtype,
-        round_kernel=args.round_kernel,
-    )
-    if args.mesh:
-        # device-resident CE: the token table rides on the index (sharded
-        # over the items axis with the payload) and the serving scorer
-        # assembles + scores pairs inside the SPMD program
-        serve_scorer = DeviceCEScorer(
-            params, lm_cfg,
-            query_token_fn=lambda q: np.asarray(ds.query_tokens)[q],
-            flash_block=(64, 64),
+    if on_tpu():
+        raise SystemExit(
+            "--cache scores the real CE from a host callback, whose nested "
+            "forward is lowered for the CPU; on a TPU that forward holds the "
+            "compiled flash kernel, which cannot lower there — drop --cache "
+            "(the CE then scores device-resident)"
         )
-        index = index.with_item_tokens(ds.item_tokens)
-        index = _shard_for_serving(index, args)
-    else:
-        serve_scorer = scorer
-    retriever = make_retriever(args.retriever, index, serve_scorer, cfg)
-    svc = AdaCURService(retriever=retriever, max_batch=args.batch)
-    _drive(svc, args, cfg,
-           qid_range=(n_anchor_q, n_anchor_q + n_serve_q),
-           label=f"real-ce/{args.retriever}" + ("/mesh" if args.mesh else ""))
-    if args.mesh:
-        print(f"device-resident CE: {serve_scorer.n_traces} in-trace forwards "
-              f"compiled (stable across batches); "
-              f"{serve_scorer.stats.batch_pad} item-shard pad rows excluded "
-              f"from {serve_scorer.stats.ce_calls} measured CE calls")
-    else:
-        inner = scorer.inner if args.cache else scorer
+
+
+def _serve_real_ce(args) -> None:
+    """End-to-end serving with the REAL transformer cross-encoder: offline
+    index built by the CE on device, online scoring inside the engine's
+    compiled program (see :func:`real_ce_service`)."""
+    refuse_real_ce_cache(args)
+    stack = build_real_ce_stack(args)
+    print(f"index built in {stack.build_s:.1f}s")
+    svc, cfg = real_ce_service(args, stack)
+    exit_if_all_failed(drive_requests(
+        svc, args, cfg, qid_range=stack.serve_qids,
+        label=f"real-ce/{args.retriever}" + ("/mesh" if args.mesh else ""),
+    ))
+    scorer = svc.retriever.score_fn
+    if args.cache:
+        inner = scorer.inner
         print(f"compiled CE shapes: {inner.n_traces} (static buckets — no "
               f"retraces); {inner.stats.batch_pad} padded micro-batch rows")
+    else:
+        print(f"device-resident CE: {scorer.n_traces} in-trace forwards "
+              f"compiled (stable across batches); "
+              f"{scorer.stats.batch_pad} item-shard pad rows excluded "
+              f"from {scorer.stats.ce_calls} measured CE calls")
 
 
 if __name__ == "__main__":

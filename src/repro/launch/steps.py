@@ -35,7 +35,6 @@ from ..models import moe as moe_lib, transformer
 from ..models.gnn import nequip
 from ..models.recsys import bert4rec, bst, dlrm, mind
 from ..training import optimizer
-from ..compat import shard_map
 
 F32, I32, BF16 = jnp.float32, jnp.int32, jnp.bfloat16
 
@@ -496,7 +495,7 @@ def _recsys_forward(cfg: RecSysConfig, mesh: Optional[Mesh] = None):
 
         def fwd(p, b):
             pspec = jax.tree.map(lambda _: P(), p)
-            return shard_map(
+            return jax.shard_map(
                 lambda pl, h: mind.retrieve(pl, h, 100, cfg),
                 mesh=mesh,
                 in_specs=(pspec, P(bspec, None)),

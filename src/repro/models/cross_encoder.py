@@ -35,7 +35,6 @@ def score_tokens(
     moe_fn=None,
     attn_impl: str = "ref",          # "flash": Pallas kernel, SMEM varlen mask
     flash_block=(128, 128),
-    flash_interpret: bool = True,
 ) -> jax.Array:
     """Exact CE score for a batch of already-concatenated pairs -> (B,).
 
@@ -47,7 +46,6 @@ def score_tokens(
     h, _ = transformer.encode(
         params, pair_tokens, cfg, kv_mask=kv_mask, moe_fn=moe_fn,
         attn_impl=attn_impl, flash_block=flash_block,
-        flash_interpret=flash_interpret,
     )
     cls = h[:, 0, :].astype(jnp.float32)
     return (cls @ params["score_head"].astype(jnp.float32))[:, 0]
@@ -95,14 +93,12 @@ def score_pairs(
     moe_fn=None,
     attn_impl: str = "ref",
     flash_block=(128, 128),
-    flash_interpret: bool = True,
 ) -> jax.Array:
     """(B, K) scores: flattens the item axis into the CE batch."""
     b, k, l = pair_tokens.shape
     flat = score_tokens(
         params, pair_tokens.reshape(b * k, l), cfg, pad_id, moe_fn,
         attn_impl=attn_impl, flash_block=flash_block,
-        flash_interpret=flash_interpret,
     )
     return flat.reshape(b, k)
 

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 
 from ...configs.base import GNNConfig
 from .. import layers
-from ...compat import shard_map
 
 # tensor-product paths computed in each interaction block
 _PATHS = (
@@ -234,7 +233,7 @@ def make_sharded_interact(mesh, node_axis: str = "data",
             "t": P(node_axis, ch, None, None),
         }
         lp_spec = jax.tree.map(lambda _: P(), lp)
-        return shard_map(
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(lp_spec, f_specs, e_spec, e_spec, P(node_axis, None),

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 
 from ..configs.base import MoEConfig
 from . import layers
-from ..compat import shard_map
 
 
 def moe_init(key, d_model: int, cfg: MoEConfig, dtype=jnp.float32):
@@ -169,7 +168,7 @@ def make_moe_fn(mesh, cfg: MoEConfig, batch_axes, ep_axis: str = "model",
             P(bspec, None),
         )
         out_specs = (P(out0, None), P())
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )(params, x)

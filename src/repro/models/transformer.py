@@ -208,7 +208,6 @@ def encode(
     attn_spec=None,                         # PartitionSpec for (B, L, H, hd)
     attn_impl: str = "ref",                 # "ref" | "flash" (Pallas kernel)
     flash_block: Tuple[int, int] = (128, 128),
-    flash_interpret: bool = True,           # interpret-mode Pallas (CPU)
 ):
     """Full forward pass. Returns (hidden (B,L,d), aux_loss[, kv caches]).
 
@@ -230,7 +229,6 @@ def encode(
             return flash_attention(
                 q, k, v, causal=cfg.causal,
                 block_q=flash_block[0], block_k=flash_block[1],
-                interpret=flash_interpret,
                 kv_lens=None if mask is None else mask.sum(-1).astype(jnp.int32),
             )
     elif attn_impl == "ref":

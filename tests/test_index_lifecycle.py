@@ -15,6 +15,7 @@ from repro.configs.base import AdaCURConfig
 from repro.core.engine import AdaCURRetriever, ANNCURRetriever, RerankRetriever
 from repro.core.index import AnchorIndex, build_r_anc
 from repro.data.synthetic import make_synthetic_ce
+from repro.launch.mesh import make_mesh
 
 CFG = AdaCURConfig(
     k_anchor=20, n_rounds=4, budget_ce=40, k_retrieve=10, loop_mode="fori"
@@ -318,7 +319,7 @@ class TestExternalItemIds:
 class TestShardedTopk:
     def test_single_device_shard_parity(self, dom):
         index = AnchorIndex.from_r_anc(dom["m"][:40], capacity=320)
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         sharded = index.shard(mesh)
         e_q = jax.random.normal(jax.random.PRNGKey(3), (5, 40))
         v0, i0 = index.topk(e_q, 8, tile=64)

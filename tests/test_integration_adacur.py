@@ -92,7 +92,8 @@ class TestFusedKernelConsistency:
         sel = jnp.zeros((4, 2000), bool).at[rows, anchor].set(True)
         ref_v, ref_i = jax.lax.top_k(jnp.where(sel, -1e30, s_hat), 16)
 
-        v, i = approx_topk_op(e_q, r_anc, anchor, 16, tile=256, interpret=True)
+        v, i = approx_topk_op(e_q, r_anc, 16, tile=256, interpret=True,
+                              mask=sel)
         np.testing.assert_allclose(np.asarray(v), np.asarray(ref_v), rtol=1e-4, atol=1e-4)
         np.testing.assert_array_equal(np.asarray(i), np.asarray(ref_i))
 

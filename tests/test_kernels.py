@@ -106,6 +106,12 @@ class TestFlashAttention:
             )
 
 
+def _anchor_mask(anchors, n):
+    """(B, N) bool selected-mask of a (B, A) anchor-id list."""
+    rows = jnp.arange(anchors.shape[0])[:, None]
+    return jnp.zeros((anchors.shape[0], n), bool).at[rows, anchors].set(True)
+
+
 class TestApproxTopK:
     @pytest.mark.parametrize("impl", ["pallas", "scan"])
     @pytest.mark.parametrize(
@@ -117,8 +123,10 @@ class TestApproxTopK:
         e_q = jax.random.normal(ks[0], (b, kq))
         r = jax.random.normal(ks[1], (kq, n))
         anchors = jax.random.randint(ks[2], (b, a), 0, n)
-        v1, i1 = approx_topk_op(e_q, r, anchors, k, tile=tile, interpret=True, impl=impl)
-        v2, i2 = approx_topk_reference(e_q, r, anchors, k)
+        mask = _anchor_mask(anchors, n)
+        v1, i1 = approx_topk_op(e_q, r, k, tile=tile, interpret=True, impl=impl,
+                                mask=mask)
+        v2, i2 = approx_topk_reference(e_q, r, k, mask=mask)
         assert_allclose(np.asarray(v1), np.asarray(v2), atol=1e-4, rtol=1e-4)
         # fused and dense rankings are BIT-equal, not merely set-equal:
         # per-column dots agree bitwise and ties break by ascending index
@@ -135,9 +143,10 @@ class TestApproxTopK:
         r = jax.random.normal(ks[1], (40, 1200))
         anchors = jax.random.randint(ks[2], (3, 8), 0, 1200)
         g = jax.random.gumbel(ks[3], (3, 1200), dtype=jnp.float32)
-        v1, i1 = approx_topk_op(e_q, r, anchors, 16, tile=256, interpret=True,
-                                noise=g, impl=impl)
-        v2, i2 = approx_topk_reference(e_q, r, anchors, 16, noise=g)
+        mask = _anchor_mask(anchors, 1200)
+        v1, i1 = approx_topk_op(e_q, r, 16, tile=256, interpret=True,
+                                noise=g, mask=mask, impl=impl)
+        v2, i2 = approx_topk_reference(e_q, r, 16, noise=g, mask=mask)
         assert_allclose(np.asarray(v1), np.asarray(v2), atol=1e-4, rtol=1e-4)
         np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
 
@@ -146,11 +155,10 @@ class TestApproxTopK:
         ks = jax.random.split(jax.random.PRNGKey(6), 4)
         e_q = jax.random.normal(ks[0], (2, 32))
         r = jax.random.normal(ks[1], (32, 900))
-        anchors = jnp.full((2, 1), -1, jnp.int32)
         mask = jax.random.bernoulli(ks[2], 0.2, (2, 900))
-        v1, i1 = approx_topk_op(e_q, r, anchors, 12, tile=128, interpret=True,
+        v1, i1 = approx_topk_op(e_q, r, 12, tile=128, interpret=True,
                                 mask=mask, n_valid=800, impl=impl)
-        v2, i2 = approx_topk_reference(e_q, r, anchors, 12, mask=mask, n_valid=800)
+        v2, i2 = approx_topk_reference(e_q, r, 12, mask=mask, n_valid=800)
         assert_allclose(np.asarray(v1), np.asarray(v2), atol=1e-4, rtol=1e-4)
         assert (np.asarray(i1) < 800).all()
         assert not np.asarray(jnp.take_along_axis(mask, i1, axis=1)).any()
@@ -165,11 +173,9 @@ class TestApproxTopK:
         # scores cycle through 4 exact levels -> ~194 exact ties per level
         levels = jnp.arange(n, dtype=jnp.float32) % 4
         r = jnp.broadcast_to(levels[None, :], (kq, n))
-        v1, i1 = approx_topk_op(e_q, r, None, k, tile=128, interpret=True,
+        v1, i1 = approx_topk_op(e_q, r, k, tile=128, interpret=True,
                                 impl=impl)
-        v2, i2 = approx_topk_reference(
-            e_q, r, jnp.full((2, 1), -1, jnp.int32), k
-        )
+        v2, i2 = approx_topk_reference(e_q, r, k)
         np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
         np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))
         # the contract itself: equal-valued winners appear in id order
@@ -191,10 +197,10 @@ class TestApproxTopK:
         # quantization error bound: half an lsb per entry
         err = jnp.abs(quant.dequantize(p) - r)
         assert float(err.max()) <= float(p.col_scales().max()) * 0.5 + 1e-6
-        anchors = jax.random.randint(ks[2], (3, 6), 0, 1333)
-        v1, i1 = approx_topk_op(e_q, p, anchors, 16, tile=256, interpret=True,
-                                impl=impl)
-        v2, i2 = approx_topk_reference(e_q, p, anchors, 16)
+        mask = _anchor_mask(jax.random.randint(ks[2], (3, 6), 0, 1333), 1333)
+        v1, i1 = approx_topk_op(e_q, p, 16, tile=256, interpret=True,
+                                mask=mask, impl=impl)
+        v2, i2 = approx_topk_reference(e_q, p, 16, mask=mask)
         assert_allclose(np.asarray(v1), np.asarray(v2), atol=1e-5, rtol=1e-5)
         np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
 
@@ -207,11 +213,10 @@ class TestApproxTopK:
         p = quant.quantize_ranc(jax.random.normal(ks[1], (32, 900)), tile=128)
         mask = jax.random.bernoulli(ks[2], 0.2, (2, 900))
         g = jax.random.gumbel(ks[3], (2, 900), dtype=jnp.float32)
-        v1, i1 = approx_topk_op(e_q, p, None, 12, tile=128, interpret=True,
+        v1, i1 = approx_topk_op(e_q, p, 12, tile=128, interpret=True,
                                 noise=g, mask=mask, n_valid=800, impl=impl)
         v2, i2 = approx_topk_reference(
-            e_q, p, jnp.full((2, 1), -1, jnp.int32), 12,
-            noise=g, mask=mask, n_valid=800,
+            e_q, p, 12, noise=g, mask=mask, n_valid=800,
         )
         assert_allclose(np.asarray(v1), np.asarray(v2), atol=1e-5, rtol=1e-5)
         np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
@@ -221,8 +226,7 @@ class TestApproxTopK:
         ks = jax.random.split(jax.random.PRNGKey(1), 3)
         e_q = jax.random.normal(ks[0], (3, 48))
         r = jax.random.normal(ks[1], (48, 1500))
-        anchors = jnp.full((3, 4), -1, jnp.int32)
-        v, i = approx_topk_op(e_q, r, anchors, 20, tile=256, interpret=True)
+        v, i = approx_topk_op(e_q, r, 20, tile=256, interpret=True)
         v = np.asarray(v)
         assert (np.diff(v, axis=1) <= 1e-6).all()
         for row in np.asarray(i):
@@ -238,9 +242,9 @@ class TestApproxTopK:
         ks = jax.random.split(jax.random.PRNGKey(seed), 3)
         e_q = jax.random.normal(ks[0], (2, 32))
         r = jax.random.normal(ks[1], (32, n))
-        anchors = jax.random.randint(ks[2], (2, 6), 0, n)
-        v1, _ = approx_topk_op(e_q, r, anchors, k, tile=256, interpret=True)
-        v2, _ = approx_topk_reference(e_q, r, anchors, k)
+        mask = _anchor_mask(jax.random.randint(ks[2], (2, 6), 0, n), n)
+        v1, _ = approx_topk_op(e_q, r, k, tile=256, interpret=True, mask=mask)
+        v2, _ = approx_topk_reference(e_q, r, k, mask=mask)
         assert_allclose(np.asarray(v1), np.asarray(v2), atol=1e-4, rtol=1e-4)
 
 
@@ -263,9 +267,6 @@ class TestPersistentRound:
         return {
             "e_q": jax.random.normal(jax.random.fold_in(key, 1), (self.B, self.KQ)),
             "r": jax.random.normal(jax.random.fold_in(key, 2), (self.KQ, self.N)),
-            "anchors": jax.random.randint(
-                jax.random.fold_in(key, 3), (self.B, 7), 0, self.N
-            ).astype(jnp.int32),
             "mask": jax.random.bernoulli(
                 jax.random.fold_in(key, 4), 0.1, (self.B, self.N)
             ),
@@ -291,13 +292,13 @@ class TestPersistentRound:
     @pytest.mark.parametrize("n_valid", [None, 700])
     def test_dual_output_bitwise_vs_staged(self, dom, dtype, impl, n_valid):
         p = self._payload(dom, dtype)
-        ref_s = approx_topk_op(dom["e_q"], p, dom["anchors"], 20, tile=256,
+        ref_s = approx_topk_op(dom["e_q"], p, 20, tile=256,
                                noise=dom["noise"], mask=dom["mask"],
                                n_valid=n_valid)
-        ref_p = approx_topk_op(dom["e_q"], p, None, 15, tile=256,
+        ref_p = approx_topk_op(dom["e_q"], p, 15, tile=256,
                                mask=dom["prov_mask"], n_valid=n_valid)
         s, prov = persistent_round_op(
-            dom["e_q"], p, k_sample=20, k_prov=15, anchors=dom["anchors"],
+            dom["e_q"], p, k_sample=20, k_prov=15,
             mask=dom["mask"], prov_mask=dom["prov_mask"], noise=dom["noise"],
             n_valid=n_valid, tile=256, interpret=True, impl=impl,
         )
@@ -310,9 +311,9 @@ class TestPersistentRound:
         """noise_key path: the kernel regenerates blocked_gumbel per tile
         from global coordinates — identical to passing the full field."""
         p = self._payload(dom, dtype)
-        ref_s = approx_topk_op(dom["e_q"], p, None, 20, tile=256,
+        ref_s = approx_topk_op(dom["e_q"], p, 20, tile=256,
                                noise=dom["noise"], mask=dom["mask"])
-        ref_p = approx_topk_op(dom["e_q"], p, None, 15, tile=256,
+        ref_p = approx_topk_op(dom["e_q"], p, 15, tile=256,
                                mask=dom["prov_mask"])
         s, prov = persistent_round_op(
             dom["e_q"], p, k_sample=20, k_prov=15, mask=dom["mask"],
@@ -324,7 +325,7 @@ class TestPersistentRound:
 
     @pytest.mark.parametrize("impl", ["scan", "pallas"])
     def test_prov_only_and_fully_masked(self, dom, impl):
-        ref_p = approx_topk_op(dom["e_q"], dom["r"], None, 15, tile=256,
+        ref_p = approx_topk_op(dom["e_q"], dom["r"], 15, tile=256,
                                mask=dom["prov_mask"])
         _, prov = persistent_round_op(
             dom["e_q"], dom["r"], k_prov=15, prov_mask=dom["prov_mask"],
@@ -333,7 +334,7 @@ class TestPersistentRound:
         self._bitwise(prov, ref_p)
         # degenerate: every item masked — sentinel fill must match staged
         full = jnp.ones((self.B, self.N), bool)
-        ref = approx_topk_op(dom["e_q"], dom["r"], None, 10, tile=256, mask=full)
+        ref = approx_topk_op(dom["e_q"], dom["r"], 10, tile=256, mask=full)
         s, _ = persistent_round_op(dom["e_q"], dom["r"], k_sample=10, mask=full,
                                    tile=256, interpret=True, impl=impl)
         self._bitwise(s, ref)
@@ -347,10 +348,10 @@ class TestPersistentRound:
         scan-vs-pallas FMA fusion corner), so cross-impl comparison would
         test the staged kernels, not the persistent one."""
         p = self._payload(dom, "int4")
-        ref_s = approx_topk_op(dom["e_q"], p, dom["anchors"], 20, tile=tile,
+        ref_s = approx_topk_op(dom["e_q"], p, 20, tile=tile, mask=dom["mask"],
                                noise=dom["noise"], impl=impl, interpret=True)
         s, _ = persistent_round_op(dom["e_q"], p, k_sample=20,
-                                   anchors=dom["anchors"], noise=dom["noise"],
+                                   mask=dom["mask"], noise=dom["noise"],
                                    tile=tile, interpret=True, impl=impl)
         self._bitwise(s, ref_s)
 
@@ -361,10 +362,10 @@ class TestPersistentRound:
         sharded persistent engine bit-identical to single-device."""
         ro, co = 3, 256
         big = blocked_gumbel(dom["nkey"], self.B + ro, self.N + co)
-        ref = approx_topk_op(dom["e_q"], dom["r"], dom["anchors"], 20,
+        ref = approx_topk_op(dom["e_q"], dom["r"], 20, mask=dom["mask"],
                              tile=256, noise=big[ro:, co:])
         s, _ = persistent_round_op(
-            dom["e_q"], dom["r"], k_sample=20, anchors=dom["anchors"],
+            dom["e_q"], dom["r"], k_sample=20, mask=dom["mask"],
             noise_key=dom["nkey"], row_offset=ro, col_offset=co,
             tile=256, interpret=True, impl=impl,
         )

@@ -176,8 +176,6 @@ def check_cross_pod_reduce():
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
     g_pod = [
         {"w": jax.random.normal(jax.random.PRNGKey(i), (8, 8))} for i in range(2)
@@ -205,7 +203,7 @@ def check_cross_pod_reduce():
             g = {"w": gp["w"][0]}          # this pod's partial
             out, new_e = cross_pod_body(g, e)
             return out, new_e
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=({"w": P("pod", "data", "model")}, {"w": P("data", "model")}),
             out_specs=({"w": P("data", "model")}, {"w": P("data", "model")}),
@@ -234,8 +232,9 @@ def check_anchor_index_shard():
     from repro.configs.base import AdaCURConfig
     from repro.core.engine import AdaCURRetriever
     from repro.core.index import AnchorIndex
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     r = jax.random.normal(jax.random.PRNGKey(0), (24, 1000))
     index = AnchorIndex.from_r_anc(r, capacity=1024)   # padded, n_valid=1000
     sharded = index.shard(mesh)
@@ -284,8 +283,9 @@ def check_quantized_index_shard():
 
     from repro.core.index import AnchorIndex
     from repro.kernels.approx_topk.quant import QuantizedRanc
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     tile = 16
     r = jax.random.normal(jax.random.PRNGKey(0), (24, 1000))
     index = AnchorIndex.from_r_anc(r, capacity=1024).quantize("int8", tile=tile)
@@ -746,7 +746,42 @@ def check_engine_device_ce():
     assert sc.n_traces == n0, (sc.n_traces, n0)
 
 
+def check_bulk_score_mesh():
+    """The offline bulk CE build split over a mesh: every device scores an
+    equal share of the items (N not a multiple of the device count) through
+    the flash kernel, and the gathered rows equal the single-device
+    build's, on one device."""
+    import jax, jax.numpy as jnp, numpy as np
+
+    from repro.configs import registry
+    from repro.configs.base import replace
+    from repro.core.scorer import DeviceCEScorer
+    from repro.data.synthetic import make_zeshel_like
+    from repro.launch.mesh import make_serving_mesh
+    from repro.models import cross_encoder
+
+    ds = make_zeshel_like(0, n_items=13, n_queries=6, item_len=12, query_len=8)
+    cfg_lm = replace(
+        registry.CE_TINY, n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
+        head_dim=16, d_ff=128, vocab_size=ds.vocab_size, dtype="float32",
+        remat=False,
+    )
+    params, _ = cross_encoder.init_cross_encoder(jax.random.PRNGKey(0), cfg_lm)
+    sc = DeviceCEScorer(
+        params, cfg_lm, query_token_fn=lambda q: np.asarray(ds.query_tokens)[q],
+        item_tokens=ds.item_tokens, len_buckets=(32, 64), attn_impl="flash",
+        flash_block=(16, 16),
+    )
+    sc.BULK_PAIRS = 4         # 2 items per step: few interpreted flash calls
+    q, items = jnp.arange(2), jnp.arange(13)
+    one = np.asarray(sc.bulk_score(q, items))
+    out = sc.bulk_score(q, items, mesh=make_serving_mesh(2, 4))
+    assert out.shape == (2, 13) and len(out.devices()) == 1, out.sharding
+    np.testing.assert_array_equal(np.asarray(out), one)
+
+
 CHECKS = {
+    "bulk_score_mesh": check_bulk_score_mesh,
     "decode_attention": check_decode_attention,
     "moe_ep": check_moe_ep,
     "gnn_interact": check_gnn_interact,
@@ -807,6 +842,7 @@ def test_serve_real_ce_mesh():
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve",
          "--scorer", "real-ce", "--mesh", "2x2", "--n-items", "128",
+         "--item-len", "24", "--query-len", "16", "--anchor-queries", "32",
          "--requests", "8", "--batch", "8", "--budget", "16", "--rounds", "2"],
         env=env, capture_output=True, text=True, timeout=560, cwd=_ROOT,
     )

@@ -327,6 +327,15 @@ class TestDeviceCEScorer:
             )
         assert device_scorer.n_traces == n0          # bucketed: no retraces
 
+    def test_bulk_score_matches_exact_matrix(self, ce_setup, device_scorer):
+        """The on-device bulk path (index build, brute force) scores every
+        pair like the host CE — padded item chunks included — and counts
+        nothing as serving cost."""
+        q = jnp.arange(3, 8)
+        out = np.asarray(device_scorer.bulk_score(q, jnp.arange(80)))
+        np.testing.assert_allclose(out, ce_setup["m"][3:8], atol=1e-4, rtol=1e-4)
+        assert device_scorer.stats.ce_calls == 0
+
     def test_bucket_overflow_raises_eagerly(self, ce_setup):
         ds, (params, cfg_lm) = ce_setup["ds"], ce_setup["lm"]
         sc = DeviceCEScorer(

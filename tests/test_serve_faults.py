@@ -140,6 +140,39 @@ class TestFlushErrorBoundary:
         assert ok.status == "ok" and ok.error is None
         assert (0 <= ok.item_ids).all() and (ok.item_ids < N_ITEMS).all()
 
+    def test_all_error_run_exits_nonzero(self, m):
+        """The serve CLI exits non-zero when every response is an error,
+        and not when at least one request was served."""
+        from repro.launch import serve
+
+        plan = FaultPlan(scorer_faults=[ScorerFault(call_k=1)])
+        svc = _service(m, plan=plan, batch_buckets=[1, 2, 4])
+        svc.submit(RetrievalRequest(query_id=3))
+        svc.submit(RetrievalRequest(query_id=7))
+        failed = svc.flush()
+        with pytest.raises(SystemExit, match="all 2 responses were errors"):
+            serve.exit_if_all_failed(failed)
+        svc.submit(RetrievalRequest(query_id=3))
+        serve.exit_if_all_failed(failed + svc.flush())
+
+    @pytest.mark.parametrize("mesh,tpu", [("1x2", False), (None, True)])
+    def test_real_ce_cache_refused_where_it_cannot_run(self, monkeypatch,
+                                                       mesh, tpu):
+        """--cache's host-callback CE is refused under --mesh and on a TPU
+        before anything is built; on one CPU device it is allowed."""
+        from repro.launch import serve
+
+        monkeypatch.setattr(serve, "on_tpu", lambda: tpu)
+        args = serve.build_arg_parser().parse_args(
+            ["--scorer", "real-ce", "--cache"]
+            + (["--mesh", mesh] if mesh else [])
+        )
+        with pytest.raises(SystemExit, match="drop --cache"):
+            serve.refuse_real_ce_cache(args)
+        args.mesh = None
+        monkeypatch.setattr(serve, "on_tpu", lambda: False)
+        serve.refuse_real_ce_cache(args)
+
     def test_fault_raises_at_exact_call(self, m):
         plan = FaultPlan(scorer_faults=[ScorerFault(call_k=3)])
         scorer = FaultyScorer(TabulatedScorer(m), plan)
