@@ -50,6 +50,7 @@ import numpy as np
 from ..configs.base import AdaCURConfig
 from ..kernels.approx_topk import quant
 from ..kernels.approx_topk.ops import approx_topk_op
+from . import telemetry
 from .adacur import AdaCURResult, ScoreFn
 from .engine import _IndexBacked, ce_call_plan, engine_search
 
@@ -396,24 +397,25 @@ class HybridRetriever(_IndexBacked):
         return run
 
     def search(self, query, key=None, n_rounds=None, **_ignored) -> AdaCURResult:
-        key = jax.random.PRNGKey(0) if key is None else key
-        cand = self.generator(query, self.shortlist_k)
-        if self.cfg.loop_mode == "fori":
-            n_rounds = jnp.asarray(
-                self.cfg.n_rounds if n_rounds is None else n_rounds, jnp.int32
-            )
-        elif n_rounds is not None:
-            raise ValueError("runtime n_rounds override requires loop_mode='fori'")
-        if self.mode == "subset":
-            r_anc, item_ids, n_valid = self._operands()
-            b = jax.tree_util.tree_leaves(query)[0].shape[0]
+        with telemetry.span("engine.dispatch"):
+            key = jax.random.PRNGKey(0) if key is None else key
+            cand = self.generator(query, self.shortlist_k)
+            if self.cfg.loop_mode == "fori":
+                n_rounds = jnp.asarray(
+                    self.cfg.n_rounds if n_rounds is None else n_rounds, jnp.int32
+                )
+            elif n_rounds is not None:
+                raise ValueError("runtime n_rounds override requires loop_mode='fori'")
+            if self.mode == "subset":
+                r_anc, item_ids, n_valid = self._operands()
+                b = jax.tree_util.tree_leaves(query)[0].shape[0]
+                return self._run(
+                    r_anc, item_ids, n_valid, query, cand, key, n_rounds,
+                    capacity=self._capacity(b),
+                )
+            r_anc, kw = self._search_operands()
+            n_items = r_anc.shape[1]
+            eligible = candidate_eligibility(cand, n_items, per_query=True)
             return self._run(
-                r_anc, item_ids, n_valid, query, cand, key, n_rounds,
-                capacity=self._capacity(b),
+                r_anc, query, key, n_rounds=n_rounds, eligible=eligible, **kw
             )
-        r_anc, kw = self._search_operands()
-        n_items = r_anc.shape[1]
-        eligible = candidate_eligibility(cand, n_items, per_query=True)
-        return self._run(
-            r_anc, query, key, n_rounds=n_rounds, eligible=eligible, **kw
-        )

@@ -61,7 +61,7 @@ from ..kernels.approx_topk.ops import approx_topk_op
 from ..kernels.approx_topk.persistent import persistent_round_op
 from ..kernels.approx_topk.quant import QuantizedRanc
 from ..kernels.backend import on_tpu
-from . import cur, sampling
+from . import cur, sampling, telemetry
 from .adacur import AdaCURResult, ScoreFn
 
 
@@ -1505,7 +1505,10 @@ class _IndexBacked:
         batch through the scorer's host tokenizer (once, before the round
         loop); every other scorer passes the query through untouched."""
         tok = getattr(self.score_fn, "tokenize_queries", None)
-        return query if tok is None else tok(query)
+        if tok is None:
+            return query
+        with telemetry.span("engine.tokenize"):
+            return tok(query)
 
     def _search_operands(self):
         if self.index is None:
@@ -1571,30 +1574,32 @@ class AdaCURRetriever(_IndexBacked):
 
     def search(self, query, key=None, first_anchors=None, batch=None,
                n_rounds=None, deadline_t=None, **_ignored):
-        key = jax.random.PRNGKey(0) if key is None else key
-        query = self._prep_query(query)
-        r_anc, kw = self._search_operands()
-        if deadline_t is None:
-            return self._run(
-                r_anc, query, key, first_anchors=first_anchors, batch=batch,
-                n_rounds=n_rounds, **kw,
-            )
-        if self.deadline is None:
+        if deadline_t is not None and self.deadline is None:
             raise ValueError("deadline_t= requires anytime=True at construction")
-        # arm -> run -> *block* -> disarm: the dispatch is async, so the
-        # deadline must stay armed until the round loop has actually executed;
-        # ``deadline.fired`` then tells the caller whether the answer is a
-        # provisional (degraded) top-k of ``rounds_done`` rounds.
-        self.deadline.arm(deadline_t)
-        try:
-            res = self._run(
-                r_anc, query, key, first_anchors=first_anchors, batch=batch,
-                n_rounds=n_rounds, **kw,
-            )
-            jax.block_until_ready(res.topk_idx)
-            return res
-        finally:
-            self.deadline.disarm()
+        with telemetry.span("engine.dispatch"):
+            key = jax.random.PRNGKey(0) if key is None else key
+            query = self._prep_query(query)
+            r_anc, kw = self._search_operands()
+            if deadline_t is None:
+                return self._run(
+                    r_anc, query, key, first_anchors=first_anchors,
+                    batch=batch, n_rounds=n_rounds, **kw,
+                )
+            # arm -> run -> *block* -> disarm: the dispatch is async, so the
+            # deadline must stay armed until the round loop has executed (the
+            # span then covers the device's run too); ``deadline.fired`` then
+            # tells the caller whether the answer is a provisional (degraded)
+            # top-k of ``rounds_done`` rounds.
+            self.deadline.arm(deadline_t)
+            try:
+                res = self._run(
+                    r_anc, query, key, first_anchors=first_anchors,
+                    batch=batch, n_rounds=n_rounds, **kw,
+                )
+                jax.block_until_ready(res.topk_idx)
+                return res
+            finally:
+                self.deadline.disarm()
 
 
 @dataclass
@@ -1658,18 +1663,19 @@ class ANNCURRetriever(_IndexBacked):
                    base_cfg, index=index, jit=jit)
 
     def search(self, query, key=None, **kw):
-        key = jax.random.PRNGKey(0) if key is None else key
-        query = self._prep_query(query)
-        anchors = (
-            self.index.anchor_item_pos
-            if self.anchor_idx is None else self.anchor_idx
-        )
-        b = jax.tree_util.tree_leaves(query)[0].shape[0]
-        first = jnp.broadcast_to(
-            anchors[None, :].astype(jnp.int32), (b, anchors.shape[0])
-        )
-        r_anc, opkw = self._search_operands()
-        return self._run(r_anc, query, key, first_anchors=first, **opkw)
+        with telemetry.span("engine.dispatch"):
+            key = jax.random.PRNGKey(0) if key is None else key
+            query = self._prep_query(query)
+            anchors = (
+                self.index.anchor_item_pos
+                if self.anchor_idx is None else self.anchor_idx
+            )
+            b = jax.tree_util.tree_leaves(query)[0].shape[0]
+            first = jnp.broadcast_to(
+                anchors[None, :].astype(jnp.int32), (b, anchors.shape[0])
+            )
+            r_anc, opkw = self._search_operands()
+            return self._run(r_anc, query, key, first_anchors=first, **opkw)
 
 
 @dataclass
@@ -1717,11 +1723,12 @@ class RerankRetriever(_IndexBacked):
     def search(self, query, key=None, candidate_idx=None, **kw):
         if candidate_idx is None:
             raise ValueError("RerankRetriever.search needs candidate_idx (B, >=budget)")
-        key = jax.random.PRNGKey(0) if key is None else key
-        query = self._prep_query(query)
-        first = candidate_idx[:, : self.budget_ce].astype(jnp.int32)
-        r_anc, opkw = self._search_operands()
-        return self._run(r_anc, query, key, first_anchors=first, **opkw)
+        with telemetry.span("engine.dispatch"):
+            key = jax.random.PRNGKey(0) if key is None else key
+            query = self._prep_query(query)
+            first = candidate_idx[:, : self.budget_ce].astype(jnp.int32)
+            r_anc, opkw = self._search_operands()
+            return self._run(r_anc, query, key, first_anchors=first, **opkw)
 
 
 # ---------------------------------------------------------------------------

@@ -58,6 +58,7 @@ import numpy as np
 
 from ..configs.base import LMConfig
 from ..kernels.backend import resolve_attn_impl
+from . import telemetry
 
 
 @dataclass
@@ -328,7 +329,9 @@ class DeviceCEScorer:
     Accounting stays *measured*: a numpy-only counting callback (no device
     compute, mesh-legal) observes each executed scoring round, so
     ``stats.ce_calls`` equals :func:`repro.core.engine.ce_call_plan` at
-    runtime and item-shard pad rows are excluded by construction.
+    runtime and item-shard pad rows are excluded by construction.  The
+    callback also records a ``ce.round`` mark (``core/telemetry``) as it
+    arrives on the host.
     """
 
     device_resident = True
@@ -474,6 +477,7 @@ class DeviceCEScorer:
 
     def _count_host(self, idx, n_pad):
         idx = np.asarray(idx)
+        telemetry.mark("ce.round", pairs=int(idx.size), pad=int(n_pad))
         self.stats.requests += 1
         self.stats.pairs += int(idx.size)
         self.stats.ce_calls += int(idx.size)

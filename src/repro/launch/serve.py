@@ -74,6 +74,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import AdaCURConfig
+from ..core import telemetry
 from ..core.engine import (
     AdaCURRetriever,
     ANNCURRetriever,
@@ -278,63 +279,70 @@ class AdaCURService:
     def _flush_batch(self, batch: List[RetrievalRequest]) -> List[RetrievalResponse]:
         n_valid = len(batch)
         bucket = self._bucket(n_valid)
-        # partial fill: pad to the static bucket by repeating the last row;
-        # the padding is sliced off before responses are built
-        raw = [r.query_id for r in batch] + [batch[-1].query_id] * (bucket - n_valid)
-        qids = jnp.asarray(raw)
-        if self.deterministic:
-            sub = self._key
-        else:
-            self._key, sub = jax.random.split(self._key)
-        kw = {}
-        if self.candidate_fn is not None:
-            kw["candidate_idx"] = self.candidate_fn(qids)
-        # anytime serving: an armed deadline is batch-global (one round loop
-        # serves all rows), so the tightest request deadline governs
-        holder = getattr(self.retriever, "deadline", None)
-        budgets = [r.deadline_t for r in batch if r.deadline_t is not None]
-        if budgets and holder is not None:
-            kw["deadline_t"] = min(budgets)
-        before = self.scorer_stats
-        before = before.copy() if before is not None else None
-        res = self.retriever.search(qids, sub, **kw)
-        res = jax.block_until_ready(res)
-        degraded = bool(holder.fired) if "deadline_t" in kw else False
-        rounds = res.rounds_done
-        rounds = int(np.asarray(rounds)) if rounds is not None else None
-        measured = cache_hits = None
-        if before is not None:
-            delta = self.scorer_stats - before
-            # amortized over the REAL requests: padded filler rows are a
-            # cost of serving them, so their calls are not averaged away
-            measured = delta.ce_calls // n_valid
-            cache_hits = delta.cache_hits
-        # single source of truth: an index-backed retriever may have been
-        # mutated directly (retriever.index = ...), so map positions through
-        # ITS index, not a possibly-stale service copy
-        idx = getattr(self.retriever, "index", None)
-        if idx is None:
-            idx = self.index
-        item_ids = (
-            np.asarray(idx.gather_item_ids(res.topk_idx))
-            if idx is not None else np.asarray(res.topk_idx)
-        )
-        out = []
-        for i, r in enumerate(batch):
-            out.append(
-                RetrievalResponse(
-                    query_id=r.query_id,
-                    item_ids=item_ids[i],
-                    scores=np.asarray(res.topk_scores[i]),
-                    latency_s=time.monotonic() - r.arrival_t,
-                    ce_calls=res.ce_calls,
-                    measured_ce_calls=measured,
-                    cache_hits=cache_hits,
-                    degraded=degraded,
-                    rounds_completed=rounds,
+        with telemetry.span("serve.flush", bucket=bucket, n_real=n_valid,
+                            arrival_t=[r.arrival_t for r in batch]) as flush:
+            with telemetry.span("serve.prepare"):
+                # partial fill: pad to the static bucket by repeating the last
+                # row; the padding is sliced off before responses are built
+                raw = [r.query_id for r in batch]
+                raw += [batch[-1].query_id] * (bucket - n_valid)
+                qids = jnp.asarray(raw)
+                if self.deterministic:
+                    sub = self._key
+                else:
+                    self._key, sub = jax.random.split(self._key)
+                kw = {}
+                if self.candidate_fn is not None:
+                    kw["candidate_idx"] = self.candidate_fn(qids)
+                # anytime serving: an armed deadline is batch-global (one
+                # round loop serves all rows), so the tightest one governs
+                holder = getattr(self.retriever, "deadline", None)
+                budgets = [r.deadline_t for r in batch if r.deadline_t is not None]
+                if budgets and holder is not None:
+                    kw["deadline_t"] = min(budgets)
+                before = self.scorer_stats
+                before = before.copy() if before is not None else None
+            res = self.retriever.search(qids, sub, **kw)    # span engine.dispatch
+            with telemetry.span("serve.device_wait"):
+                res = jax.block_until_ready(res)
+            with telemetry.span("serve.respond"):
+                degraded = bool(holder.fired) if "deadline_t" in kw else False
+                rounds = res.rounds_done
+                rounds = int(np.asarray(rounds)) if rounds is not None else None
+                flush["rounds"] = rounds
+                measured = cache_hits = None
+                if before is not None:
+                    delta = self.scorer_stats - before
+                    # amortized over the REAL requests: padded filler rows are a
+                    # cost of serving them, so their calls are not averaged away
+                    measured = delta.ce_calls // n_valid
+                    cache_hits = delta.cache_hits
+                # single source of truth: an index-backed retriever may have been
+                # mutated directly (retriever.index = ...), so map positions through
+                # ITS index, not a possibly-stale service copy
+                idx = getattr(self.retriever, "index", None)
+                if idx is None:
+                    idx = self.index
+                item_ids = (
+                    np.asarray(idx.gather_item_ids(res.topk_idx))
+                    if idx is not None else np.asarray(res.topk_idx)
                 )
-            )
-        return out
+                out = []
+                for i, r in enumerate(batch):
+                    out.append(
+                        RetrievalResponse(
+                            query_id=r.query_id,
+                            item_ids=item_ids[i],
+                            scores=np.asarray(res.topk_scores[i]),
+                            latency_s=time.monotonic() - r.arrival_t,
+                            ce_calls=res.ce_calls,
+                            measured_ce_calls=measured,
+                            cache_hits=cache_hits,
+                            degraded=degraded,
+                            rounds_completed=rounds,
+                        )
+                    )
+                return out
 
 
 def make_retriever(
