@@ -26,6 +26,10 @@ Where the program records (one flush of ``launch/serve.AdaCURService``):
   engine program runs, from ``DeviceCEScorer``'s counting callback.  A mark
   belongs to the flush whose dispatch-to-ready interval (``engine.dispatch``
   start to ``serve.device_wait`` end) holds it.
+
+Outside a flush, ``kernels/flash_attention`` records a ``flash.schedule``
+mark (attrs ``schedule``, ``pairs``, ``seq``, ``heads``) each time it is
+traced: which schedule a compiled attention shape took.
 """
 
 from __future__ import annotations

@@ -1,6 +1,7 @@
 """Pallas TPU kernels for the perf-critical compute layers.
 
-- flash_attention: CE prefill attention (blocked online softmax, GQA)
+- flash_attention: CE prefill attention (whole-sequence schedule for short
+                   bidirectional pairs, blocked online softmax otherwise; GQA)
 - approx_topk:     fused ADACUR approx-score GEMM + masked top-k
 - embedding_bag:   scalar-prefetch gather+reduce for recsys tables
 

@@ -19,7 +19,8 @@ from repro.kernels.approx_topk.ref import approx_topk_reference
 from repro.core.sampling import blocked_gumbel
 from repro.kernels.embedding_bag.ops import embedding_bag_op
 from repro.kernels.embedding_bag.ref import embedding_bag_reference
-from repro.kernels.flash_attention.kernel import flash_attention
+from repro.core import telemetry
+from repro.kernels.flash_attention.kernel import flash_attention, pairs_per_step
 from repro.kernels.flash_attention.ref import attention_reference
 
 
@@ -104,6 +105,73 @@ class TestFlashAttention:
             assert_allclose(
                 np.asarray(out[i, :n]), np.asarray(ref[0]), atol=3e-5, rtol=3e-5
             )
+
+    # the whole-sequence schedule: bidirectional pairs at the served head
+    # layouts (BERT-base 12 x 64 in 256 tokens, MiniLM-L6 12 x 32 in 128)
+    @pytest.mark.parametrize(
+        "b,l,h,kv,hd,dtype,ragged",
+        [
+            (4, 256, 12, 12, 64, jnp.float32, False),
+            (4, 256, 12, 12, 64, jnp.bfloat16, False),
+            (8, 128, 12, 12, 32, jnp.float32, False),
+            (8, 128, 12, 12, 32, jnp.bfloat16, False),
+            (4, 256, 12, 12, 64, jnp.bfloat16, True),
+            (8, 128, 12, 12, 32, jnp.float32, True),
+            (4, 128, 8, 2, 32, jnp.float32, True),     # GQA 4:1
+            (5, 128, 12, 12, 32, jnp.float32, True),   # 5 pairs in blocks of 4
+        ],
+    )
+    def test_whole_sequence_matches_reference(self, b, l, h, kv, hd, dtype, ragged):
+        ks = jax.random.split(jax.random.PRNGKey(b * l + hd), 4)
+        q = jax.random.normal(ks[0], (b, l, h, hd)).astype(dtype)
+        k = jax.random.normal(ks[1], (b, l, kv, hd)).astype(dtype)
+        v = jax.random.normal(ks[2], (b, l, kv, hd)).astype(dtype)
+        lens = (jax.random.randint(ks[3], (b,), 1, l + 1) if ragged
+                else jnp.full((b,), l, jnp.int32))
+        assert pairs_per_step(b, l, l, h, kv, hd, jnp.dtype(dtype).itemsize) > 0
+        out = flash_attention(q, k, v, causal=False, kv_lens=lens, interpret=True)
+        tol = 2e-5 if dtype == jnp.float32 else 2e-2
+        for i in range(b):
+            n = int(lens[i])
+            ref = attention_reference(
+                q[i : i + 1, :n], k[i : i + 1, :n], v[i : i + 1, :n], causal=False
+            )
+            assert_allclose(
+                np.asarray(out[i, :n], np.float32), np.asarray(ref[0], np.float32),
+                atol=tol, rtol=tol,
+            )
+
+    @pytest.mark.parametrize(
+        "b,l,h,hd,causal,schedule",
+        [
+            (1600, 256, 12, 64, False, "whole_sequence"),   # BERT-base CE rerank
+            (320, 128, 12, 32, False, "whole_sequence"),    # MiniLM-L6 CE round
+            (1600, 256, 12, 64, True, "online_softmax"),
+            (2, 2048, 12, 64, False, "online_softmax"),     # KV past VMEM
+        ],
+    )
+    def test_schedule_choice(self, b, l, h, hd, causal, schedule):
+        """The shape picks the schedule, and each traced call says which in
+        one ``flash.schedule`` mark; the online-softmax grid is the
+        (B*H, Lq/128, Lk/128) one."""
+        qkv = jax.ShapeDtypeStruct((b, l, h, hd), jnp.bfloat16)
+        lens = jax.ShapeDtypeStruct((b,), jnp.int32)
+        telemetry.reset()
+        jaxpr = jax.make_jaxpr(
+            lambda q, k, v, n: flash_attention(
+                q, k, v, causal=causal, kv_lens=n, interpret=True)
+        )(qkv, qkv, qkv, lens)
+        marks = [m for m in telemetry.snapshot().marks if m.name == "flash.schedule"]
+        assert [m.attrs for m in marks] == [
+            {"schedule": schedule, "pairs": b, "seq": l, "heads": h}]
+        (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        bp = pairs_per_step(b, l, l, h, h, hd, 2)
+        if schedule == "whole_sequence":
+            assert bp > 0 and b % bp == 0
+            assert call.params["grid_mapping"].grid == (b // bp,)
+        else:
+            assert causal or bp == 0
+            assert call.params["grid_mapping"].grid == (b * h, l // 128, l // 128)
 
 
 def _anchor_mask(anchors, n):

@@ -3,7 +3,8 @@
 Nothing runs: the TPU compiler installed next to JAX compiles each kernel
 for one chip of a described ``v5e:2x2`` topology at the served shapes
 (batch 16, k_q 100 anchor queries, 10,240 items, float32 / int8 / fp8
-payloads; the CE's head_dim 32 in the 256-token bucket), and each test asserts that the executable holds the
+payloads; the CE's head_dim 32 in the 256-token bucket, and the two served
+CE head layouts), and each test asserts that the executable holds the
 compiled kernel (``tpu_custom_call``).  This is what the interpret-mode
 tests cannot see: a block shape Mosaic refuses, a primitive it cannot
 lower, more VMEM than a core has.
@@ -13,7 +14,9 @@ so every test worker collects the same tests and only the worker given this
 file loads the TPU compiler.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -79,6 +82,16 @@ def _kernel_calls(text: str, name: str) -> int:
     )
 
 
+def _relayouts(text: str, n_elements: int) -> list:
+    """The copies and transposes of arrays of ``n_elements`` elements."""
+    found = []
+    for line in text.splitlines():
+        m = re.search(r"= \w+\[([\d,]*)\]\S* (copy|transpose)\(", line)
+        if m and math.prod(int(d) for d in m.group(1).split(",") if d) == n_elements:
+            found.append(line.strip()[:120])
+    return found
+
+
 # v5e has no fp8 unit: fp8 codes compile to a software widen in the kernel
 @pytest.mark.parametrize("dtype", ["float32", "int8", "fp8"])
 @pytest.mark.parametrize("k", [K_SAMPLE, K_RERANK])
@@ -124,6 +137,25 @@ def test_flash_attention_with_kv_lens_compiles(one_chip):
         _shape(one_chip, (PAIRS,), jnp.int32),
     )
     assert _kernel_calls(text, "flash_attention") == 1
+
+
+# the served CE layouts: BERT-base (1,600 rerank pairs of 256 tokens, 12 x 64
+# heads) and MiniLM-L6 (320 round pairs of 128 tokens, 12 x 32 heads)
+@pytest.mark.parametrize("pairs,seq,heads,head_dim",
+                         [(1600, 256, 12, 64), (320, 128, 12, 32)])
+def test_flash_attention_served_layouts_compile(one_chip, pairs, seq, heads,
+                                                head_dim):
+    """One kernel call, fed q/k/v in the layout the chip keeps them in: no
+    copy or transpose of q, k, v or the output surrounds it."""
+    def attend(q, k, v, lens):
+        return flash_attention(q, k, v, causal=False, kv_lens=lens,
+                               interpret=False)
+
+    qkv = _shape(one_chip, (pairs, seq, heads, head_dim), jnp.bfloat16)
+    text = _compiled_text(attend, qkv, qkv, qkv,
+                          _shape(one_chip, (pairs,), jnp.int32))
+    assert _kernel_calls(text, "flash_attention") == 1
+    assert _relayouts(text, pairs * seq * heads * head_dim) == []
 
 
 def test_int4_payload_refused_by_name(one_chip):
