@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import argparse
 import copy
+import importlib
 import json
 from dataclasses import dataclass
 from pathlib import Path
@@ -43,45 +44,15 @@ def seed_key(seed: int):
     return jax.random.fold_in(key, (seed >> 32) & 0x7FFFFFFF)
 
 
+def family(cfg: dict):
+    """The configuration's architecture family (``families/<reference>.py``),
+    found by the same name as its plain reference."""
+    return importlib.import_module(f"families.{cfg['reference']}")
+
+
 def lm_config(cfg: dict):
-    """The program's encoder configuration for a benchmark config file."""
-    from repro.configs.base import LMConfig
-
-    heads = cfg["num_attention_heads"]
-    return LMConfig(
-        name=cfg["name"], n_layers=cfg["num_hidden_layers"],
-        d_model=cfg["hidden_size"], n_heads=heads, n_kv_heads=heads,
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        head_dim=cfg["hidden_size"] // heads, qkv_bias=True,
-        rope_theta=cfg["rope_theta"], tie_embeddings=True, causal=False,
-        act="gelu", norm="layernorm", mlp_bias=True,
-        max_seq_len=cfg["max_position_embeddings"], dtype=cfg["torch_dtype"],
-        remat=False,
-    )
-
-
-def _init_leaf(path: str, key, shape, dtype, cfg: dict):
-    import jax
-    import jax.numpy as jnp
-
-    name = path.rsplit("/", 1)[-1]
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    normal = jax.random.normal(key, shape, jnp.float32)
-    if name == "w" and "norm" in path or name == "w" and "/ln" in path:
-        x = 1.0 + 0.02 * normal
-    elif name in ("b", "bq", "bk", "bv", "bu", "bd"):
-        x = 0.02 * normal
-    elif name == "embed":
-        x = normal
-    elif name in ("wq", "wk", "wv", "wu", "score_head"):
-        x = normal / np.sqrt(d)
-    elif name == "wo":
-        x = normal / np.sqrt(d)
-    elif name == "wd":
-        x = normal / np.sqrt(f)
-    else:
-        raise ValueError(f"no initializer for weight {path}")
-    return x.astype(dtype)
+    """The program's model configuration for a benchmark config file."""
+    return family(cfg).program_config(cfg)
 
 
 def make_weights(lm_cfg, cfg: dict, key):
@@ -91,6 +62,7 @@ def make_weights(lm_cfg, cfg: dict, key):
 
     from repro.models import cross_encoder
 
+    init_leaf = family(cfg).init_leaf
     shapes = jax.eval_shape(
         lambda: cross_encoder.init_cross_encoder(jax.random.PRNGKey(0), lm_cfg)[0]
     )
@@ -102,7 +74,7 @@ def make_weights(lm_cfg, cfg: dict, key):
     def build(key):
         keys = jax.random.split(key, len(flat))
         return jax.tree_util.tree_unflatten(tree, [
-            _init_leaf(path, k, s.shape, s.dtype, cfg)
+            init_leaf(path, k, s.shape, s.dtype, cfg)
             for path, k, (_, s) in zip(paths, keys, flat)
         ])
 
@@ -198,9 +170,7 @@ def build(cfg: dict, traffic: dict, seed: int, log=lambda what: None) -> Cell:
     serving = traffic_kind(traffic["kind"]).SERVICE
     cell.scorer = DeviceCEScorer(
         params, lm_cfg, query_token_fn=lambda q: cell.query_tokens[q],
-        item_tokens=None if serving else items,
-        pad_id=cfg["pad_token_id"], cls_id=cfg["cls_token_id"],
-        sep_id=cfg["sep_token_id"],
+        item_tokens=None if serving else items, **family(cfg).scorer_kwargs(cfg),
     )
     if serving:
         _build_service(cell, seed)

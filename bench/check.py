@@ -27,17 +27,19 @@ import numpy as np
 
 
 def engine_shape(cfg: dict, c) -> dict:
+    from cell import family
+
     eng, dep = cfg["engine"], cfg["deployment"]
+    att = family(cfg).attention(cfg)
     return dict(
         k_q=dep["k_q"], n_items=dep["n_items"], rounds=eng["rounds"],
         budget=eng["budget"], k_anchor=eng["k_anchor"],
         k_s=eng["k_anchor"] // eng["rounds"],
         k_r=eng["budget"] - eng["k_anchor"], k_retrieve=eng["k_retrieve"],
         tile=getattr(c.engine_cfg, "fused_tile", None),
-        layers=cfg["num_hidden_layers"], seq_len=dep["pair_len"],
-        heads=cfg["num_attention_heads"],
-        head_dim=cfg["hidden_size"] // cfg["num_attention_heads"],
-        pinv_rcond=eng["pinv_rcond"],
+        layers=att["layers"], seq_len=att["seq_len"], heads=att["heads"],
+        head_dim=att["qk_head_dim"], v_head_dim=att["v_head_dim"],
+        causal=att["causal"], pinv_rcond=eng["pinv_rcond"],
     )
 
 

@@ -17,7 +17,8 @@ def read(ctx):
         return None
     e = ctx.engine
     flops, nbytes = ctx.kernel_cost(KERNEL).cost(
-        ctx.trace_pairs, e["seq_len"], e["heads"], e["head_dim"])
+        ctx.trace_pairs, e["seq_len"], e["heads"], e["head_dim"],
+        v_head_dim=e["v_head_dim"], causal=e["causal"])
     flops, nbytes = flops * e["layers"], nbytes * e["layers"]
     least = max(flops / ctx.peaks["bf16_flops_per_s"],
                 nbytes / ctx.peaks["hbm_bytes_per_s"])

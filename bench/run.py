@@ -10,7 +10,8 @@ against the plain references in ``bench/references``.  With ``--trace 0``
 the result line carries the cell's end-to-end metrics, with ``--trace 1``
 its per-layer metrics, read from a profiler trace of the window.
 
-Everything is found by name: ``bench/configs/<config>.json``,
+Everything is found by name: ``bench/configs/<config>.json`` and the
+architecture family its ``reference`` key names, ``bench/families/<reference>.py``,
 ``bench/traffic/<traffic>.json`` and the kind it names,
 ``bench/traffic/<kind>.py``, ``bench/metrics/<metric>.py``,
 ``bench/kernels/<kernel>.py`` and ``bench/peaks.json``.  The run refuses
@@ -241,7 +242,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
                     else win.ce_pairs)
     ctx = RunContext(
         workload, cfg, traffic, win, setup_s, peaks,
-        kernel_cost("cross_encoder").flops_per_pair_of(cfg), eng, summary,
+        cell_mod.family(cfg).flops_per_pair(cfg), eng, summary,
         traced_pairs, [b.bucket for b in win.batches if b.t1 <= win.t_end],
     )
     metrics = {}

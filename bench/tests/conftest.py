@@ -13,15 +13,7 @@ for p in (BENCH.parent / "src", BENCH):
     if str(p) not in sys.path:
         sys.path.insert(0, str(p))
 
-# every cell's configuration cut to a size a CPU test can run: the widths
-# and lengths shrink, the engine, service and traffic kinds stay
-TINY_CONFIG = {
-    "hidden_size": 64, "num_attention_heads": 4, "num_hidden_layers": 2,
-    "intermediate_size": 128,
-    "deployment": {"n_items": 600, "query_len": 13, "item_len": 16,
-                   "pair_len": 32, "n_queries": 64},
-    "service": {"max_batch": 4, "batch_buckets": [1, 2, 4]},
-}
+# each traffic kind's mix cut to a CPU test's length
 TINY_TRAFFIC = {
     "closed": {"clients": 4, "check_requests": 4, "check_cur_requests": 12},
     "poisson": {"rate_qps": 20.0, "check_requests": 4, "check_cur_requests": 12},
@@ -29,5 +21,11 @@ TINY_TRAFFIC = {
 }
 
 
-def tiny(kind: str) -> dict:
-    return {"config": TINY_CONFIG, "traffic": TINY_TRAFFIC[kind]}
+def tiny(kind: str, config: str) -> dict:
+    """Overrides that run a cell of ``config`` under a traffic ``kind`` at
+    a CPU test's size: the cut its architecture family states (``TINY``)
+    and a short mix."""
+    import cell
+
+    return {"config": cell.family(cell.load_json("configs", config)).TINY,
+            "traffic": TINY_TRAFFIC[kind]}

@@ -16,10 +16,10 @@ BUILD = "zeshel-yugioh.bert-base.index-build"
 
 
 def _run(workload, seed=2**33 + 3, control=False):
-    kind = {"bulk": "closed", "poisson-20": "poisson", "index-build": "index_build"}[
-        workload.rsplit(".", 1)[1]]
+    config, mix = workload.rsplit(".", 1)
+    kind = {"bulk": "closed", "poisson-20": "poisson", "index-build": "index_build"}[mix]
     return run.run(workload, seed, 2.0, False, require_tpu=False,
-                   overrides=tiny(kind), compile_cache=False, control=control)
+                   overrides=tiny(kind, config), compile_cache=False, control=control)
 
 
 def _failing(result):
@@ -28,7 +28,8 @@ def _failing(result):
 
 def test_run_refuses_a_host_without_tpu():
     with pytest.raises(SystemExit) as e:
-        run.run(BULK, 1, 1.0, False, overrides=tiny("closed"), compile_cache=False)
+        run.run(BULK, 1, 1.0, False, overrides=tiny("closed", "zeshel-yugioh.bert-base"),
+                compile_cache=False)
     assert e.value.code != 0
 
 

@@ -39,3 +39,32 @@ def test_sweep_cost_counts_payload_and_mask():
     assert flops == 2 * 16 * 200 * 10031
     payload, mask = 200 * 10031 * 4, 16 * 10031
     assert payload + mask < nbytes < payload + mask + 16 * 200 * 4 + 2 * 2 * 16 * 20 * 4 + 1
+
+
+@pytest.mark.parametrize("pairs, seq_len, heads, head_dim", [
+    (1, 256, 12, 64), (14000, 256, 12, 64), (123456, 128, 12, 32), (7, 33, 5, 24),
+])
+def test_flash_cost_under_defaults_is_the_bidirectional_formula(pairs, seq_len, heads, head_dim):
+    """Equal head dims and no mask: 4 pairs H L^2 hd FLOPs and 4 pairs L H hd
+    bytes, the counts every cell's flash roofline was read with, to the bit."""
+    flops, nbytes = kernel_cost("flash_attention").cost(pairs, seq_len, heads, head_dim)
+    assert flops == 4.0 * pairs * heads * seq_len * seq_len * head_dim
+    assert nbytes == 4.0 * pairs * seq_len * heads * head_dim * 2
+    assert (flops, nbytes) == kernel_cost("flash_attention").cost(
+        pairs, seq_len, heads, head_dim, v_head_dim=head_dim, causal=False)
+
+
+def test_flash_cost_causal_and_latent_hand_count():
+    cost = kernel_cost("flash_attention").cost
+    # one pair of 4 tokens, one head, q/k 192 and v 128 (latent attention):
+    # a causal mask keeps 1 + 2 + 3 + 4 = 10 (query, key) pairs, each 192
+    # MACs of QK^T and 128 of PV
+    flops, nbytes = cost(1, 4, 1, 192, v_head_dim=128, causal=True)
+    assert flops == 2 * 10 * (192 + 128)
+    assert nbytes == 4 * (192 + 192 + 128 + 128) * 2      # q, k, v in, o out
+    # without the mask all 16 pairs count; bytes do not depend on the mask
+    assert cost(1, 4, 1, 192, v_head_dim=128) == (2 * 16 * (192 + 128), nbytes)
+    # a layer of Moonlight's shape: 16 heads, 256-token causal pairs
+    flops, nbytes = cost(1, 256, 16, 192, v_head_dim=128, causal=True)
+    assert flops == 2 * 16 * (256 * 257 // 2) * 320
+    assert nbytes == 256 * 16 * 640 * 2

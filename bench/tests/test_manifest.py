@@ -1,5 +1,6 @@
 """BENCHMARK.json against the rules a benchmark manifest keeps."""
 
+import importlib
 import json
 import re
 
@@ -95,3 +96,22 @@ def test_config_file_states_its_source_and_cuts(config):
     assert all(cfg[k] != v for k, v in cfg["source_values"].items())
     assert (BENCH / "references" / f"{cfg['reference']}.py").is_file()
     assert any(w["config"] == config for w in MAN["workloads"])
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in MAN["configs"]])
+def test_config_family_exposes_the_hooks(config):
+    """Every configuration's architecture family, named by its ``reference``
+    key, exists beside its reference and exposes every hook the harness
+    calls."""
+    import families
+
+    cfg = json.loads((ROOT / f"bench/configs/{config}.json").read_text())
+    assert (BENCH / "families" / f"{cfg['reference']}.py").is_file()
+    fam = importlib.import_module(f"families.{cfg['reference']}")
+    for hook in families.HOOKS:
+        assert hasattr(fam, hook), hook
+    assert all(callable(getattr(fam, h)) for h in families.HOOKS if h != "TINY")
+    assert isinstance(fam.TINY, dict)
+    att = fam.attention(cfg)
+    assert set(att) == {"layers", "heads", "qk_head_dim", "v_head_dim", "seq_len", "causal"}
+    assert fam.flops_per_pair(cfg) > 0

@@ -30,7 +30,8 @@ def test_traced_run_reports_program_span_metrics(workload, monkeypatch):
 
     monkeypatch.setattr(generator, "measure", kept)
     result = run.run(workload, 2**33 + 5, 3.0, True, require_tpu=False,
-                     overrides=tiny(kind), compile_cache=False)
+                     overrides=tiny(kind, workload.rsplit(".", 1)[0]),
+                     compile_cache=False)
     metrics = {k: v["value"] for k, v in result["metrics"].items()}
     for name in names:
         assert metrics[f"{name}.{suffix}"] > 0, (name, metrics)

@@ -49,7 +49,8 @@ def test_ops_exclude_loops_and_rank_the_kernel_first(summary):
 def test_flash_roofline_reader(summary):
     from run import metric_reader
 
-    engine = dict(seq_len=256, heads=12, head_dim=64, layers=12)
+    engine = dict(seq_len=256, heads=12, head_dim=64, v_head_dim=64, causal=False,
+                  layers=12)
     peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
     ctx = SimpleNamespace(trace=summary, trace_pairs=14000, engine=engine,
                           peaks=peaks, kernel_cost=kernel_cost)

@@ -28,9 +28,10 @@ def test_build_schedule_is_seeded():
 
 
 def test_tables_and_weights_are_seeded():
-    from conftest import TINY_CONFIG
+    from conftest import tiny
 
-    cfg = cell.merge(cell.load_json("configs", "zeshel-yugioh.bert-base"), TINY_CONFIG)
+    cfg = cell.merge(cell.load_json("configs", "zeshel-yugioh.bert-base"),
+                     tiny("closed", "zeshel-yugioh.bert-base")["config"])
     lm = cell.lm_config(cfg)
     one = [cell.make_tables(cfg, cell.seed_key(2**32 + 9)),
            cell.make_weights(lm, cfg, cell.seed_key(2**32 + 9))]
